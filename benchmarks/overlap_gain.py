@@ -120,6 +120,9 @@ def main(min_speedup: float = 1.3, json_path=None) -> list[dict]:
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    # virtual CPU devices by design: the child never contends for a chip
+    # that this (parent) process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=1800)
     if res.returncode != 0:

@@ -22,6 +22,9 @@ def main(argv=None) -> int:
                     help="write per-suite timings/rows as JSON")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (chaos_recovery, dispatch_overhead, fig13_scaling,
                    overlap_gain, roofline, serve_load, table2_saxpy,
                    table3_particle, table4_flux, table5_eikonal,

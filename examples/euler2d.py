@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (Boundary, DistTensor, Executor, Graph, Layout,
                         MaxReducer, RecordArray, SumReducer,
                         exclusive_padded_access, make_mesh,
@@ -166,6 +167,7 @@ if __name__ == "__main__":
                     help="print the full dependency-DAG schedule "
                          "(describe_dag) before running")
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.nx, args.ny, args.steps, args.devices, px=args.px,
         overlap=args.overlap, unsplit=args.unsplit,
         show_dag=args.show_dag)

@@ -17,6 +17,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (DistTensor, Executor, Graph, Layout, MaxReducer,
                         make_reduction_result)
 from repro.kernels.particle.ops import PARTICLE_SPEC, particle_update
@@ -99,4 +100,5 @@ if __name__ == "__main__":
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--show-dag", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.n, args.steps, show_dag=args.show_dag)

@@ -10,10 +10,13 @@ in sync (a tested doc-example).
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (Boundary, DistTensor, Executor, Graph, Layout,
                         RecordArray, RecordSpec, SumReducer, Vector,
                         concurrent_padded_access, execute,
                         make_reduction_result, preferred_layout, relayout)
+
+enable_compile_cache()
 
 # ---------------------------------------------------------------------------
 # 1. Polymorphic layout (paper Listing 2): one record type, two layouts

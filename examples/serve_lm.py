@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 import repro.configs as configs
+from repro.compile_cache import enable_compile_cache
 from repro.models.lm import init_lm
 
 
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--max-gen", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch)
     params, _ = init_lm(cfg, jax.random.PRNGKey(0), tp=1)

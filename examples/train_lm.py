@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 import repro.configs as configs
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.data import SyntheticLM
 from repro.launch.steps import make_train_step
 from repro.models.lm import init_lm, param_count
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--big", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = model_config(args.big)
     print(f"[train_lm] params: {param_count(cfg):,} "

@@ -2,8 +2,4 @@
 framework: polymorphic data layout, haloed distributed tensors, graph
 scheduling, Pallas TPU kernels, and an LM train/serve stack on top."""
 
-from . import compat as _compat
-
-_compat.install()  # version-guarded jax shims (no-op on modern JAX)
-
 __version__ = "0.1.0"

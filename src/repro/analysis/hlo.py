@@ -559,17 +559,8 @@ class CostRanker:
 
 
 def normalize_cost_analysis(cost) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns a per-device *list* of dicts (one per addressable
-    device); newer JAX returns the dict directly.  Always hand back a
-    dict (element 0 of a list — the numbers are identical across devices
-    for SPMD programs), and ``{}`` for None/empty."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a plain dict (``{}`` for None)."""
+    return dict(cost or {})
 
 
 def analyze_hlo(hlo_text: str) -> dict:

@@ -106,9 +106,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map_compat
 from ..runtime.faults import (HostTimeoutError, TransientError,
                               trip as _fault_trip)
 from ..tuning.tiles import tile_scope
@@ -126,18 +125,11 @@ __all__ = ["Executor", "execute", "make_mesh", "LayoutPlan", "RelayoutStep",
            "layout_candidates", "plan_signature", "ExecutableCacheEntry",
            "clear_executable_cache", "executable_cache_stats"]
 
-# version-guarded shard_map accepting the modern kwarg set — bound here so
-# the executor does not depend on repro/__init__'s global jax monkeypatch
-shard_map = shard_map_compat()
-
-
 def make_mesh(shape, axis_names) -> Mesh:
-    """make_mesh with Auto axis types, version-guarded: older JAX installs
-    have neither ``jax.sharding.AxisType`` nor the ``axis_types`` kwarg
-    (the single guard implementation lives in ``repro.compat``)."""
-    from ..compat import make_mesh_auto
-
-    return make_mesh_auto(shape, axis_names)
+    """``jax.make_mesh`` with Auto axis types over the first
+    ``prod(shape)`` devices."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 @dataclass
@@ -1886,9 +1878,9 @@ class Executor:
                 # too (an initially-false condition runs nothing)
                 return lax.while_loop(sub_graph.condition, body_fn, s)
 
-            fn = shard_map(shard_body, mesh=self.mesh,
-                           in_specs=(specs,), out_specs=specs,
-                           check_vma=False)
+            fn = jax.shard_map(shard_body, mesh=self.mesh,
+                               in_specs=(specs,), out_specs=specs,
+                               check_vma=False)
             return fn(state)
         return lax.while_loop(sub_graph.condition, body_fn, state)
 
@@ -1923,14 +1915,31 @@ class Executor:
             state[name] = data
         return state
 
-    def _donate_split(self, entry_layouts, exit_layouts):
-        """State keys whose storage shape is stable across a region (same
-        layout at entry and exit) — only those are donated, so XLA can
-        actually alias them and jax never warns about unusable donations."""
-        return frozenset(
+    def _state_split(self, written, entry_layouts, exit_layouts):
+        """``(donated, read_only)`` state keys of one executable.
+
+        Only keys whose storage shape is stable across it (same layout at
+        entry and exit) can be aliased.  Of those, a key the executable
+        never writes is read-only: it is passed in but neither donated
+        nor returned, so the caller keeps its buffer and XLA copies
+        nothing (returning an undonated input would copy it on every
+        call — a model's weights, for one).  The other stable keys are
+        donated, and jax never warns about unusable donations."""
+        stable = frozenset(
             k for k in list(self.tensors) + list(self.results)
             if k not in entry_layouts
             or entry_layouts[k] is exit_layouts.get(k, entry_layouts[k]))
+        read_only = frozenset(k for k in stable if k not in written)
+        return stable - read_only, read_only
+
+    @staticmethod
+    def _split_state(state: dict, donate_keys, read_only) -> tuple:
+        """``state`` as the (donated, kept, read-only) executable args."""
+        parts = ({}, {}, {})
+        for k, v in state.items():
+            parts[0 if k in donate_keys else 2 if k in read_only
+                  else 1][k] = v
+        return parts
 
     def _fetch(self, key, build: Callable) -> Callable:
         """One executable from the plan-wide cache, building on miss.
@@ -1953,20 +1962,22 @@ class Executor:
         shard_map, loop segments as inlined while_loops."""
         chain, exit_layouts = self._segment_chain(region.segments,
                                                   entry_layouts)
-        donate_keys = self._donate_split(entry_layouts, exit_layouts)
+        donate_keys, read_only = self._state_split(
+            self._region_access[region.index][1], entry_layouts,
+            exit_layouts)
         cache_entry = self._cache
         sharded = self._sharded
 
-        def region_call(donated, kept):
+        def region_call(donated, kept, consts):
             cache_entry.trace_events += 1   # Python body runs per trace only
-            state = {**donated, **kept}
+            state = {**donated, **kept, **consts}
             for si, conv, layouts in chain:
                 state = self._traced_convert(dict(state), conv, layouts)
                 kind, payload = self._segments[si]
                 if kind == "device":
                     if sharded:
                         specs = self._state_specs(state, layouts)
-                        fn = shard_map(
+                        fn = jax.shard_map(
                             partial(self._lower_levels, payload,
                                     sharded=True, layouts=layouts),
                             mesh=self.mesh, in_specs=(specs,),
@@ -1977,22 +1988,22 @@ class Executor:
                                                    layouts)
                 else:  # 'loop'
                     state = self._lower_loop(payload, si, state)
-            return state
+            return {k: v for k, v in state.items() if k not in read_only}
 
         jfn = jax.jit(region_call,
                       donate_argnums=(0,) if self.donate else ())
         tile_config = self._tile_config
 
         def invoke(state):
-            donated = {k: v for k, v in state.items() if k in donate_keys}
-            kept = {k: v for k, v in state.items() if k not in donate_keys}
+            args = self._split_state(state, donate_keys, read_only)
             # the (tuned) tile config only matters while the body traces;
             # steady-state calls hit the jit cache and never read it
             with tile_scope(tile_config):
-                return jfn(donated, kept)
+                return {**args[2], **jfn(*args)}
 
         invoke.jit_fn = jfn
         invoke.donate_keys = donate_keys
+        invoke.read_only = read_only
         invoke.exit_layouts = exit_layouts
         return invoke
 
@@ -2012,10 +2023,9 @@ class Executor:
             raise ValueError(f"region {index} is {region.kind!r}, "
                              f"not a device region")
         fn, _ = self._region_executable(region)
-        donated = {k: v for k, v in state.items() if k in fn.donate_keys}
-        kept = {k: v for k, v in state.items() if k not in fn.donate_keys}
+        args = self._split_state(state, fn.donate_keys, fn.read_only)
         with tile_scope(self._tile_config):
-            return fn.jit_fn.lower(donated, kept).compile().as_text()
+            return fn.jit_fn.lower(*args).compile().as_text()
 
     # -- segment compilation (regions=False per-segment dispatch) -----------
     def _device_fn(self, levels) -> Callable:
@@ -2031,8 +2041,8 @@ class Executor:
         # specs must cover exactly the state dict; build lazily per call
         def call(state):
             specs = self._state_specs(state, self._state_layouts)
-            fn = shard_map(body, mesh=self.mesh, in_specs=(specs,),
-                           out_specs=specs, check_vma=False)
+            fn = jax.shard_map(body, mesh=self.mesh, in_specs=(specs,),
+                               out_specs=specs, check_vma=False)
             return fn(state)
 
         return jax.jit(call, donate_argnums=0 if self.donate else ())
@@ -2235,38 +2245,47 @@ class Executor:
                     current[n] = lay
         body_layouts = dict(current)
         levels = [lv for _, seg in self._segments for lv in seg]
-        donate_keys = self._donate_split(entry_layouts, body_layouts)
+        donate_keys, read_only = self._state_split(
+            schedule_lib.graph_access(self.graph)[1], entry_layouts,
+            body_layouts)
         cache_entry = self._cache
         sharded = self._sharded
 
-        def call(donated, kept, steps):
+        def call(donated, kept, consts, steps):
             cache_entry.trace_events += 1
             state = self._traced_convert({**donated, **kept}, convs,
                                          body_layouts)
 
-            def body(_, s):
-                return self._lower_levels(levels, s, sharded, body_layouts)
+            def loop(st, cs, n):
+                # read-only entries ride outside the loop carry
+                def body(_, s):
+                    out = self._lower_levels(levels, {**s, **cs}, sharded,
+                                             body_layouts)
+                    return {k: out[k] for k in s}
+                return lax.fori_loop(0, n, body, st)
 
             if sharded:
-                specs = self._state_specs(state, body_layouts)
-                fn = shard_map(
-                    lambda st, n: lax.fori_loop(0, n, body, st),
-                    mesh=self.mesh, in_specs=(specs, P()),
-                    out_specs=specs, check_vma=False)
-                return fn(state, steps)
-            return lax.fori_loop(0, steps, body, state)
+                fn = jax.shard_map(
+                    loop, mesh=self.mesh,
+                    in_specs=(self._state_specs(state, body_layouts),
+                              self._state_specs(consts, body_layouts), P()),
+                    out_specs=self._state_specs(state, body_layouts),
+                    check_vma=False)
+                return fn(state, consts, steps)
+            return loop(state, consts, steps)
 
         jfn = jax.jit(call, donate_argnums=(0,) if self.donate else ())
         tile_config = self._tile_config
 
         def invoke(state, steps):
-            donated = {k: v for k, v in state.items() if k in donate_keys}
-            kept = {k: v for k, v in state.items() if k not in donate_keys}
+            args = self._split_state(state, donate_keys, read_only)
             with tile_scope(tile_config):
-                return jfn(donated, kept, jnp.asarray(steps, jnp.int32))
+                return {**args[2],
+                        **jfn(*args, jnp.asarray(steps, jnp.int32))}
 
         invoke.jit_fn = jfn
         invoke.donate_keys = donate_keys
+        invoke.read_only = read_only
         invoke.exit_layouts = body_layouts
         return invoke
 

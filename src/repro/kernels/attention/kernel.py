@@ -2,9 +2,9 @@
 layout-polymorphic KV storage (Ripple C1 applied to the KV cache).
 
 TPU mapping: q tiles of (block_q, head_dim) live in VMEM; K/V stay in
-``ANY`` (HBM) and are streamed block-by-block with running-softmax
-accumulation (online softmax).  block_q/block_k are the VMEM knobs and
-should be multiples of 128 for MXU alignment.
+``ANY`` (HBM) and are copied block-by-block into VMEM scratch buffers,
+with running-softmax accumulation (online softmax).  block_q/block_k are
+the VMEM knobs and should be multiples of 128 for MXU alignment.
 
 KV layouts (DESIGN.md §5):
   * SOA — separate ``k`` and ``v`` arrays (B, Hkv, S, D): streaming reads
@@ -28,7 +28,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.tuning.tiles import register_tile_kernel
 
 NEG_INF = -1e30
@@ -60,15 +62,15 @@ def _attn_kernel(
     q_offset: int,
     fused_kv: bool,
     q_ref,
-    *kv_refs,
+    *refs,
 ):
-    o_ref = kv_refs[-1]
-    kv_refs = kv_refs[:-1]
+    # inputs (K/V or fused KV, in ANY), the output block, then one VMEM
+    # scratch buffer per input
+    n_in = 1 if fused_kv else 2
+    kv_refs, o_ref, bufs = refs[:n_in], refs[n_in], refs[n_in + 1:]
     b = pl.program_id(0)
     h = pl.program_id(1)
     qi = pl.program_id(2)
-    group = q_ref.shape[1]  # == 1 block over q heads; see caller
-    del group
 
     q = q_ref[0, 0].astype(jnp.float32)  # (block_q, D)
     d = q.shape[-1]
@@ -76,7 +78,8 @@ def _attn_kernel(
     n_q_heads = pl.num_programs(1)
     hkv = h // max(1, n_q_heads // n_kv_heads)
 
-    q_pos = q_offset + qi * block_q + jax.lax.iota(jnp.int32, block_q)
+    q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
 
     # clip the kv loop to the causal / window range (block skipping)
     if causal:
@@ -95,37 +98,39 @@ def _attn_kernel(
 
     def load_kv(kb):
         start = kb * block_k
+        for src, buf in zip(kv_refs, bufs):
+            pltpu.sync_copy(src.at[b, hkv, pl.ds(start, block_k)], buf)
         if fused_kv:
-            kv = kv_refs[0][b, hkv, pl.ds(start, block_k)]  # (bk, 2, D)
+            kv = bufs[0][...]  # (bk, 2, D)
             return kv[:, 0].astype(jnp.float32), kv[:, 1].astype(jnp.float32)
-        k = kv_refs[0][b, hkv, pl.ds(start, block_k)].astype(jnp.float32)
-        v = kv_refs[1][b, hkv, pl.ds(start, block_k)].astype(jnp.float32)
-        return k, v
+        return (bufs[0][...].astype(jnp.float32),
+                bufs[1][...].astype(jnp.float32))
 
     def body(kb, carry):
         acc, m, l = carry
         k, v = load_kv(kb)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ()))) * scale  # (bq, bk)
-        k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
         mask = jnp.ones(s.shape, dtype=bool)
         if causal:
-            mask &= q_pos[:, None] >= k_pos[None, :]
+            mask &= q_pos >= k_pos
         if window is not None:
-            mask &= k_pos[None, :] > q_pos[:, None] - window
+            mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[:, None] + p @ v
+        l_new = l * alpha + p.sum(axis=-1, keepdims=True)
+        acc_new = acc * alpha + p @ v
         return acc_new, m_new, l_new
 
     acc = jnp.zeros((q.shape[0], d), jnp.float32)
-    m = jnp.full((q.shape[0],), NEG_INF, jnp.float32)
-    l = jnp.zeros((q.shape[0],), jnp.float32)
+    m = jnp.full((q.shape[0], 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((q.shape[0], 1), jnp.float32)
     acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc, m, l))
-    out = acc / jnp.maximum(l, 1e-20)[:, None]
+    out = acc / jnp.maximum(l, 1e-20)
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -140,7 +145,7 @@ def flash_attention_pallas(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (B, Hq, Sq, D).  SOA: k,v each (B, Hkv, Skv, D).
     AOS: pass fused kv as ``k`` with shape (B, Hkv, Skv, 2, D), v=None."""
@@ -156,12 +161,10 @@ def flash_attention_pallas(
     kern = functools.partial(
         _attn_kernel, scale, causal, window, block_q, block_k, skv,
         q_offset, fused)
-    in_specs = [pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-                pl.BlockSpec(memory_space=pl.ANY)]
-    operands = [q, k]
-    if not fused:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        operands.append(v)
+    operands = [k] if fused else [k, v]
+    in_specs = [pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(operands)
+    scratch = [pltpu.VMEM((block_k, *x.shape[3:]), x.dtype) for x in operands]
 
     return pl.pallas_call(
         kern,
@@ -169,5 +172,6 @@ def flash_attention_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-        interpret=interpret,
-    )(*operands)
+        scratch_shapes=scratch,
+        interpret=interpret_mode(interpret),
+    )(q, *operands)

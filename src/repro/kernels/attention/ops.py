@@ -14,15 +14,13 @@ from .ref import decode_ref, mha_ref
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "q_offset", "scale",
-                                   "block_q", "block_k", "use_pallas",
-                                   "interpret"))
+                                   "block_q", "block_k", "use_pallas"))
 def _flash_attention_jit(q, k, v=None, *, causal, window, q_offset,
-                         scale, block_q, block_k, use_pallas, interpret):
+                         scale, block_q, block_k, use_pallas):
     if use_pallas:
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, q_offset=q_offset,
-            scale=scale, block_q=block_q, block_k=block_k,
-            interpret=interpret)
+            scale=scale, block_q=block_q, block_k=block_k)
     if v is None:
         k, v = k[..., 0, :], k[..., 1, :]
     return mha_ref(q, k, v, causal=causal, window=window, q_offset=q_offset,
@@ -31,7 +29,7 @@ def _flash_attention_jit(q, k, v=None, *, causal, window, q_offset,
 
 def flash_attention(q, k, v=None, *, causal=True, window=None, q_offset=0,
                     scale=None, block_q=None, block_k=None,
-                    use_pallas=True, interpret=True):
+                    use_pallas=True):
     """Flash attention over layout-polymorphic KV storage.  SOA path:
     ``(q, k, v)``; AOS path: ``(q, kv_fused, None)`` with kv
     ``(B, Hkv, S, 2, D)``.
@@ -48,7 +46,7 @@ def flash_attention(q, k, v=None, *, causal=True, window=None, q_offset=0,
     return _flash_attention_jit(
         q, k, v, causal=causal, window=window, q_offset=q_offset,
         scale=scale, block_q=block_q, block_k=block_k,
-        use_pallas=use_pallas, interpret=interpret)
+        use_pallas=use_pallas)
 
 
 attention_decode = decode_ref

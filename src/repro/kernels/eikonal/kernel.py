@@ -3,9 +3,9 @@
 Solves ``|grad phi| = 1/f`` (f = 1: signed-distance reinit) with the Fast
 Iterative Method.  The paper's winning configuration stages a tile in
 shared memory and runs several update sweeps on it before writing back;
-on TPU each grid program DMAs a halo-inclusive tile into VMEM and runs
-``inner`` Jacobi sweeps with frozen halos (the FIM ghost-zone trade),
-then the outer loop (graph-level, with halo exchange + convergence
+on TPU each grid program DMAs a halo-inclusive tile into a VMEM scratch
+buffer and runs ``inner`` Jacobi sweeps on it with frozen halos (the FIM
+ghost-zone trade), then the outer loop (graph-level, with halo exchange + convergence
 reduction — paper's conditional MapReduce) repeats until converged.
 
 The Godunov upwind update in 2-D (f=1, grid step h):
@@ -23,7 +23,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.tuning.tiles import register_tile_kernel
 
 TILE_KERNEL = "eikonal"   # name in the autotuner's tile registry
@@ -34,11 +36,13 @@ def tile_candidates(shape: tuple[int, ...]) -> tuple[tuple[int, int], ...]:
     """Feasible ``(bx, by)`` FIM tile shapes for an interior of
     ``(nx, ny)`` cells (the autotuner's search axis).  Bigger tiles
     amortize the frozen-halo inner sweeps over more cells (the paper's
-    ghost-zone trade); candidates tile the interior exactly."""
+    ghost-zone trade); candidates are multiples of the (8, 128) VPU tile
+    (Mosaic refuses a block narrower than 128 lanes) that tile the
+    interior exactly."""
     nx, ny = shape
     return tuple((bx, by)
                  for bx in (8, 16, 32, 64) if bx <= nx and nx % bx == 0
-                 for by in (64, 128, 256) if by <= ny and ny % by == 0)
+                 for by in (128, 256) if by <= ny and ny % by == 0)
 
 
 register_tile_kernel(TILE_KERNEL, tile_candidates)
@@ -47,14 +51,16 @@ register_tile_kernel(TILE_KERNEL, tile_candidates)
 def godunov_update(phi: jax.Array, mask: jax.Array, h: float) -> jax.Array:
     """One Jacobi sweep on a haloed tile; interior cells updated only.
 
-    ``phi``: (m+2, n+2); ``mask``: (m, n) True where source (pinned).
-    Returns the updated *interior* (m, n).
+    ``phi``: (m+2, n+2), or a kernel ref whose leading (m+2, n+2) corner
+    is the tile; ``mask``: (m, n) True where source (pinned).  Returns the
+    updated *interior* (m, n).
     """
-    w = phi[:-2, 1:-1]
-    e = phi[2:, 1:-1]
-    s = phi[1:-1, :-2]
-    n = phi[1:-1, 2:]
-    c = phi[1:-1, 1:-1]
+    m, n_ = mask.shape
+    w = phi[0:m, 1:n_ + 1]
+    e = phi[2:m + 2, 1:n_ + 1]
+    s = phi[1:m + 1, 0:n_]
+    n = phi[1:m + 1, 2:n_ + 2]
+    c = phi[1:m + 1, 1:n_ + 1]
     a = jnp.minimum(w, e)
     b = jnp.minimum(s, n)
     lo = jnp.minimum(a, b)
@@ -67,18 +73,22 @@ def godunov_update(phi: jax.Array, mask: jax.Array, h: float) -> jax.Array:
 
 
 def _fim_kernel(bx: int, by: int, inner: int, h: float,
-                phi_ref, mask_ref, o_ref):
+                phi_ref, mask_ref, o_ref, tile_ref, sem):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    tile = phi_ref[pl.ds(i * bx, bx + 2), pl.ds(j * by, by + 2)]
-    mask = mask_ref[pl.ds(i * bx, bx), pl.ds(j * by, by)]
+    wx, wy = tile_ref.shape
+    copy = pltpu.make_async_copy(
+        phi_ref.at[pl.ds(i * bx, wx), pl.ds(j * by, wy)], tile_ref, sem)
+    copy.start()
+    copy.wait()
+    mask = mask_ref[...] != 0
 
-    def body(_, t):
-        interior = godunov_update(t, mask, h)
-        return t.at[1:-1, 1:-1].set(interior)
+    def body(_, carry):
+        tile_ref[1:bx + 1, 1:by + 1] = godunov_update(tile_ref, mask, h)
+        return carry
 
-    tile = jax.lax.fori_loop(0, inner, body, tile)
-    o_ref[...] = tile[1:-1, 1:-1]
+    jax.lax.fori_loop(0, inner, body, 0)
+    o_ref[...] = tile_ref[1:bx + 1, 1:by + 1]
 
 
 def eikonal_fim_pallas(
@@ -88,7 +98,7 @@ def eikonal_fim_pallas(
     *,
     inner: int = 4,
     block: tuple[int, int] = (8, 128),
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``inner`` VMEM-staged FIM sweeps per tile.  ``phi_haloed`` is
     (nx+2, ny+2); ``source_mask`` is (nx, ny); returns (nx, ny)."""
@@ -96,14 +106,22 @@ def eikonal_fim_pallas(
     bx, by = (min(block[0], nx), min(block[1], ny))
     assert nx % bx == 0 and ny % by == 0, (nx, ny, bx, by)
     grid = (nx // bx, ny // by)
+    # DMA windows are whole (8, 128) memory tiles: stage an aligned
+    # superset of the halo-inclusive tile, padding the input so the last
+    # window stays in bounds.  The mask rides as int32 (Mosaic blocks
+    # hold no booleans).
+    wx, wy = pl.cdiv(bx + 2, 8) * 8, pl.cdiv(by + 2, 128) * 128
+    phi = jnp.pad(phi_haloed, [(0, wx - bx - 2), (0, wy - by - 2)])
     return pl.pallas_call(
         partial(_fim_kernel, bx, by, inner, h),
         out_shape=jax.ShapeDtypeStruct((nx, ny), phi_haloed.dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((bx, by), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((bx, by), lambda i, j: (i, j)),
-        interpret=interpret,
-    )(phi_haloed, source_mask)
+        scratch_shapes=[pltpu.VMEM((wx, wy), phi_haloed.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        interpret=interpret_mode(interpret),
+    )(phi, source_mask.astype(jnp.int32))

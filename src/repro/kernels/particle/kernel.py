@@ -18,9 +18,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layout import (Layout, RecordArray, RecordRef, RecordSpec,
                                Vector, record_grid_1d)
+from repro.kernels import interpret_mode
 from repro.tuning.tiles import register_tile_kernel
 
 PARTICLE_SPEC = RecordSpec.create(Vector("x", 3), Vector("v", 3))
@@ -48,9 +50,9 @@ register_tile_kernel(TILE_KERNEL, tile_candidates)
 def _particle_kernel(spec: RecordSpec, layout: Layout, dt_ref, p_ref, o_ref):
     p = RecordRef(p_ref, spec, layout)
     o = RecordRef(o_ref, spec, layout)
-    dt = dt_ref[0]
     for c in range(3):
         x = p.get("x", c)
+        dt = dt_ref[0].astype(x.dtype)
         v = p.get("v", c)
         o.set("x", x + v * dt, c)
         o.set("v", v, c)
@@ -61,20 +63,21 @@ def particle_update_pallas(
     dt: float,
     *,
     block: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> RecordArray:
     (n,) = particles.space
     spec, layout = particles.spec, particles.layout
     assert n % block == 0, f"n={n} must tile by block={block}"
     grid, bspec = record_grid_1d(spec, layout, n, block)
 
-    dt_arr = jnp.asarray(dt, dtype=particles.dtype).reshape(1)
+    # the scalar rides in SMEM (32-bit words), the record tiles in VMEM
+    dt_arr = jnp.asarray(dt, dtype=jnp.float32).reshape(1)
     out = pl.pallas_call(
         partial(_particle_kernel, spec, layout),
         out_shape=jax.ShapeDtypeStruct(particles.data.shape, particles.dtype),
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY), bspec],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), bspec],
         out_specs=bspec,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(dt_arr, particles.data)
     return RecordArray(out, spec, layout)

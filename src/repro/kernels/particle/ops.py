@@ -13,18 +13,16 @@ from .kernel import (DEFAULT_BLOCK, PARTICLE_SPEC, PREFERRED_LAYOUT,
 from .ref import particle_update_ref
 
 
-@partial(jax.jit, static_argnames=("block", "use_pallas", "interpret"))
-def _particle_update_jit(particles, dt, *, block: int, use_pallas: bool,
-                         interpret: bool):
+@partial(jax.jit, static_argnames=("block", "use_pallas"))
+def _particle_update_jit(particles, dt, *, block: int, use_pallas: bool):
     if not use_pallas:
         return particle_update_ref(particles, dt)
     return dispatch_with_relayout(
         particle_update_pallas, particles, dt, supported=SUPPORTED_LAYOUTS,
-        preferred=PREFERRED_LAYOUT, block=block, interpret=interpret)
+        preferred=PREFERRED_LAYOUT, block=block)
 
 
-def particle_update(particles, dt, *, block=None, use_pallas: bool = True,
-                    interpret: bool = True):
+def particle_update(particles, dt, *, block=None, use_pallas: bool = True):
     """``x += v * dt`` over a particle RecordArray (paper Table 3) — one
     kernel body for AoS / SoA / AoSoA.
 
@@ -35,4 +33,4 @@ def particle_update(particles, dt, *, block=None, use_pallas: bool = True,
     block = resolve_tile(TILE_KERNEL, block, DEFAULT_BLOCK,
                          shape=particles.space)
     return _particle_update_jit(particles, dt, block=block,
-                                use_pallas=use_pallas, interpret=interpret)
+                                use_pallas=use_pallas)

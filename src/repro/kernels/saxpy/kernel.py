@@ -18,9 +18,11 @@ from functools import partial as _partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layout import (Layout, RecordArray, RecordRef, RecordSpec,
                                record_grid_1d)
+from repro.kernels import interpret_mode
 from repro.tuning.tiles import register_tile_kernel
 
 # record form: x and y live in ONE record buffer (paper §4.2's layout axis
@@ -65,7 +67,7 @@ def saxpy_pallas(
     *,
     block: int = 1024,
     bounds_check: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """y_out = a * x + y over a 1-d array, VMEM-tiled in ``block`` chunks."""
     size = x.shape[0]
@@ -94,7 +96,7 @@ def saxpy_pallas(
             pl.BlockSpec((block,), lambda i: (i,)),
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a_arr, x, y)
     return out[:size]
 
@@ -103,8 +105,8 @@ def _saxpy_record_kernel(spec: RecordSpec, layout: Layout, a_ref, p_ref,
                          o_ref):
     p = RecordRef(p_ref, spec, layout)
     o = RecordRef(o_ref, spec, layout)
-    a = a_ref[0]
     x = p.get("x")
+    a = a_ref[0].astype(x.dtype)
     o.set("x", x)
     o.set("y", a * x + p.get("y"))
 
@@ -114,7 +116,7 @@ def saxpy_record_pallas(
     a,
     *,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> RecordArray:
     """``y = a*x + y`` over a two-field record in any of the three layouts
     — the kernel body is a single :class:`RecordRef` program."""
@@ -123,13 +125,14 @@ def saxpy_record_pallas(
     assert n % block == 0, f"n={n} must tile by block={block}"
     grid, bspec = record_grid_1d(spec, layout, n, block)
 
-    a_arr = jnp.asarray(a, dtype=rec.dtype).reshape(1)
+    # the scalar rides in SMEM (32-bit words), the record tiles in VMEM
+    a_arr = jnp.asarray(a, dtype=jnp.float32).reshape(1)
     out = pl.pallas_call(
         _partial(_saxpy_record_kernel, spec, layout),
         out_shape=jax.ShapeDtypeStruct(rec.data.shape, rec.dtype),
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY), bspec],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), bspec],
         out_specs=bspec,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a_arr, rec.data)
     return RecordArray(out, spec, layout)

@@ -13,30 +13,26 @@ from .kernel import (DEFAULT_BLOCK, PREFERRED_LAYOUT, SAXPY_SPEC,
 from .ref import saxpy_record_ref, saxpy_ref
 
 
-@partial(jax.jit, static_argnames=("block", "bounds_check", "use_pallas",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("block", "bounds_check", "use_pallas"))
 def saxpy(a, x, y, *, block: int = 1024, bounds_check: bool = True,
-          use_pallas: bool = True, interpret: bool = True):
+          use_pallas: bool = True):
     """``a * x + y`` over flat arrays (paper Table 2's iterator-overhead
     probe; the record form below is the layout axis)."""
     if use_pallas:
-        return saxpy_pallas(a, x, y, block=block, bounds_check=bounds_check,
-                            interpret=interpret)
+        return saxpy_pallas(a, x, y, block=block, bounds_check=bounds_check)
     return saxpy_ref(a, x, y)
 
 
-@partial(jax.jit, static_argnames=("block", "use_pallas", "interpret"))
-def _saxpy_record_jit(rec, a, *, block: int, use_pallas: bool,
-                      interpret: bool):
+@partial(jax.jit, static_argnames=("block", "use_pallas"))
+def _saxpy_record_jit(rec, a, *, block: int, use_pallas: bool):
     if not use_pallas:
         return saxpy_record_ref(rec, a)
     return dispatch_with_relayout(
         saxpy_record_pallas, rec, a, supported=SUPPORTED_LAYOUTS,
-        preferred=PREFERRED_LAYOUT, block=block, interpret=interpret)
+        preferred=PREFERRED_LAYOUT, block=block)
 
 
-def saxpy_record(rec, a, *, block=None, use_pallas: bool = True,
-                 interpret: bool = True):
+def saxpy_record(rec, a, *, block=None, use_pallas: bool = True):
     """``y = a*x + y`` on a RecordArray with fields ``x``/``y`` — same
     kernel body under AoS, SoA and AoSoA (paper's polymorphism claim).
     A layout outside SUPPORTED_LAYOUTS is staged through PREFERRED_LAYOUT
@@ -48,5 +44,4 @@ def saxpy_record(rec, a, *, block=None, use_pallas: bool = True,
     any scope the kernel default applies.  An explicit ``block`` always
     wins."""
     block = resolve_tile(TILE_KERNEL, block, DEFAULT_BLOCK, shape=rec.space)
-    return _saxpy_record_jit(rec, a, block=block, use_pallas=use_pallas,
-                             interpret=interpret)
+    return _saxpy_record_jit(rec, a, block=block, use_pallas=use_pallas)

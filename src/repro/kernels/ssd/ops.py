@@ -9,17 +9,16 @@ from .kernel import DEFAULT_CHUNK, TILE_KERNEL, ssd_pallas
 from .ref import ssd_chunked, ssd_decode_step, ssd_naive
 
 
-@partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
+@partial(jax.jit, static_argnames=("chunk", "use_pallas"))
 def _ssd_jit(x, dt, A, Bm, C, D=None, init_state=None, *, chunk: int,
-             use_pallas: bool, interpret: bool):
+             use_pallas: bool):
     if use_pallas:
-        return ssd_pallas(x, dt, A, Bm, C, D, init_state, chunk=chunk,
-                          interpret=interpret)
+        return ssd_pallas(x, dt, A, Bm, C, D, init_state, chunk=chunk)
     return ssd_chunked(x, dt, A, Bm, C, D, init_state, chunk=chunk)
 
 
 def ssd(x, dt, A, Bm, C, D=None, init_state=None, *, chunk=None,
-        use_pallas: bool = True, interpret: bool = True):
+        use_pallas: bool = True):
     """Mamba-2 SSD: Pallas intra-chunk quadratic part + XLA inter-chunk
     scan; returns ``(y, final_state)``.
 
@@ -29,4 +28,4 @@ def ssd(x, dt, A, Bm, C, D=None, init_state=None, *, chunk=None,
     chunk = resolve_tile(TILE_KERNEL, chunk, DEFAULT_CHUNK,
                          shape=(x.shape[1],))
     return _ssd_jit(x, dt, A, Bm, C, D, init_state, chunk=chunk,
-                    use_pallas=use_pallas, interpret=interpret)
+                    use_pallas=use_pallas)

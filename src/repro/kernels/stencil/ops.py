@@ -1,11 +1,10 @@
 """Jitted public wrapper + graph builder for the FORCE flux-difference
 stencil.
 
-Layout dispatch: the Pallas kernel walks halo-inclusive tiles, which
-needs per-axis storage (AoS or SoA).  An AoSoA input is relayouted to the
-kernel's preferred layout on the way in and back on the way out — the
-same boundary conversion the executor's layout solver emits, so results
-are numerically identical under all three layouts.
+Layout dispatch: the Pallas kernel DMAs halo-inclusive SoA tiles.  An
+AoS or AoSoA input is relayouted to SoA on the way in and back on the
+way out — the same boundary conversion the executor's layout solver
+emits, so results are numerically identical under all three layouts.
 """
 
 from functools import partial
@@ -22,19 +21,19 @@ from .kernel import (DEFAULT_BLOCK, PREFERRED_LAYOUT, SUPPORTED_LAYOUTS,
 from .ref import flux_difference_ref
 
 
-@partial(jax.jit, static_argnames=("block", "use_pallas", "interpret"))
+@partial(jax.jit, static_argnames=("block", "use_pallas"))
 def _flux_difference_jit(state_haloed, lam_x, lam_y, *, block,
-                         use_pallas: bool, interpret: bool):
+                         use_pallas: bool):
     if not use_pallas:
         return flux_difference_ref(state_haloed, lam_x, lam_y)
     return dispatch_with_relayout(
         flux_difference_pallas, state_haloed, lam_x, lam_y,
         supported=SUPPORTED_LAYOUTS, preferred=PREFERRED_LAYOUT,
-        block=block, interpret=interpret)
+        block=block)
 
 
 def flux_difference(state_haloed, lam_x, lam_y, *, block=None,
-                    use_pallas: bool = True, interpret: bool = True):
+                    use_pallas: bool = True):
     """Sum of FORCE flux differences over both dims of a haloed 2-D
     Euler record (paper Table 4): ``(nx+2, ny+2)`` space in, ``(nx, ny)``
     out, layout polymorphic (AoSoA staged through the kernel's preferred
@@ -47,7 +46,7 @@ def flux_difference(state_haloed, lam_x, lam_y, *, block=None,
     interior = tuple(s - 2 for s in state_haloed.space)
     block = resolve_tile(TILE_KERNEL, block, DEFAULT_BLOCK, shape=interior)
     return _flux_difference_jit(state_haloed, lam_x, lam_y, block=block,
-                                use_pallas=use_pallas, interpret=interpret)
+                                use_pallas=use_pallas)
 
 
 def make_flux_difference_graph(
@@ -59,7 +58,6 @@ def make_flux_difference_graph(
     overlap: bool = True,
     use_pallas: bool = False,
     block=None,
-    interpret: bool = True,
     graph: Optional[Graph] = None,
 ) -> Graph:
     """One-node Ripple graph: FORCE flux difference over a (possibly
@@ -81,7 +79,7 @@ def make_flux_difference_graph(
 
     def flux_node(rec, _out):
         return flux_difference(rec, lam_x, lam_y, block=block,
-                               use_pallas=use_pallas, interpret=interpret)
+                               use_pallas=use_pallas)
 
     g = graph if graph is not None else Graph(name="flux_difference")
     g.split(flux_node, concurrent_padded_access(u), out, overlap=overlap)

@@ -12,21 +12,13 @@ in-pod DP; "model" is the TP/EP/sequence-flash-decode axis on ICI.
 
 from __future__ import annotations
 
-import jax
-
-from repro.compat import make_mesh_auto
+from repro.core.executor import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_auto(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Small-mesh helper (tests / examples) with Auto axis types
-    (version-guarded: older JAX lacks ``axis_types``)."""
-    return make_mesh_auto(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -28,19 +28,35 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro.compile_cache import enable_compile_cache
 from repro.launch import steps as S
 from repro.models.lm import init_lm
 
 
-def legacy_generate(cfg, params, batch, gen: int, max_seq: int):
-    """The pre-Ripple jit loop: prefill + uniform batched greedy decode.
-    -> (B, gen) token matrix."""
+class LegacyRun(NamedTuple):
+    """What :func:`legacy_generate` produced."""
+
+    tokens: np.ndarray          # (B, gen) greedy tokens
+    gaps: np.ndarray            # (B, gen) top-2 logit gap behind each token
+    prefill_logits: jax.Array   # (B, V) logits after the prompt
+    t_prefill: float
+    t_decode: float
+
+
+def _top2_gap(logits):
+    top2 = jax.lax.top_k(logits.astype(jnp.float32), 2)[0]
+    return np.asarray(top2[..., 0] - top2[..., 1])
+
+
+def legacy_generate(cfg, params, batch, gen: int, max_seq: int) -> LegacyRun:
+    """The pre-Ripple jit loop: prefill + uniform batched greedy decode."""
     from repro.models.blocks import ShardCtx
     from repro.models.lm import prefill as prefill_raw
 
@@ -49,17 +65,20 @@ def legacy_generate(cfg, params, batch, gen: int, max_seq: int):
     logits, caches = jax.jit(
         lambda p, b: prefill_raw(p, b, cfg, ShardCtx(), max_seq=max_seq)
     )(params, batch)
+    prefill_logits = logits
     t_prefill = time.perf_counter() - t0
     toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    out_tokens = [np.asarray(toks)]
+    out_tokens, gaps = [np.asarray(toks)], [_top2_gap(logits)]
     t1 = time.perf_counter()
     for _ in range(gen - 1):
         logits, caches = decode_fn(params, caches, toks)
         toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         out_tokens.append(np.asarray(toks))
+        gaps.append(_top2_gap(logits))
     jax.block_until_ready(toks)
     t_decode = time.perf_counter() - t1
-    return np.stack(out_tokens, axis=1), t_prefill, t_decode
+    return LegacyRun(np.stack(out_tokens, axis=1), np.stack(gaps, axis=1),
+                     prefill_logits, t_prefill, t_decode)
 
 
 def serve_legacy(cfg, params, args):
@@ -75,8 +94,8 @@ def serve_legacy(cfg, params, args):
             (B, cfg.frontend_tokens, cfg.frontend_dim)).astype(np.float32))
     max_seq = args.prompt_len + args.gen + (
         cfg.frontend_tokens if cfg.frontend_dim and not cfg.is_encdec else 0)
-    gen, t_prefill, t_decode = legacy_generate(cfg, params, batch,
-                                               args.gen, max_seq)
+    gen, _, _, t_prefill, t_decode = legacy_generate(cfg, params, batch,
+                                                     args.gen, max_seq)
     print(f"[serve] arch={cfg.name} batch={B} prompt={args.prompt_len} "
           f"gen={args.gen} path=legacy")
     print(f"[serve] prefill {t_prefill*1e3:.0f}ms; decode "
@@ -112,9 +131,9 @@ def serve_ripple(cfg, params, args):
 
     if args.smoke:
         # 1. graph-native decode == legacy jit path, token for token
-        legacy, _, _ = legacy_generate(
+        legacy = legacy_generate(
             cfg, params, {"tokens": jnp.asarray(prompts)}, args.gen,
-            max_seq)
+            max_seq).tokens
         assert (gen == legacy).all(), (
             f"ripple/legacy argmax mismatch:\n{gen}\nvs\n{legacy}")
         print("[smoke] ripple == legacy argmax sequences  OK")
@@ -200,6 +219,7 @@ def main(argv=None):
                          "and assert replay-log recovery (ripple path)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     params, _ = init_lm(cfg, jax.random.PRNGKey(0), tp=1)
     if args.legacy or cfg.is_encdec or cfg.frontend_dim:

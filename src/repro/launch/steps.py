@@ -386,10 +386,14 @@ def make_decode_step(cfg: ModelConfig, mesh: Optional[Mesh],
 # ModelConfig under it, which makes the model code layout-polymorphic
 # without a single `if` at the call site.
 #
-# Zero-trace serving: node fns close over (cfg, params, ctx).  The ctx is
-# cached per (cfg, mesh, shape) below so a worker process that rebuilds the
-# graph from the SAME cfg/params objects produces an identical plan
-# signature and serves straight from the process-wide executable cache.
+# Weights are executor state: every node takes the parameter leaves as
+# tensor args (read-only, so the executor passes them into each region
+# executable as inputs — never embedded as constants, never donated or
+# copied).  Zero-trace serving: node fns close over (cfg, ctx, the weight
+# handles).  The ctx is cached per (cfg, mesh, shape) below, and graphs per
+# (cfg, params) object, so a worker process that rebuilds them from the
+# SAME cfg/params objects produces an identical plan signature and serves
+# straight from the process-wide executable cache.
 
 _CTX_CACHE: dict = {}
 
@@ -467,6 +471,33 @@ def serving_cache_slots(cfg: ModelConfig, batch: int, max_seq: int,
     return tuple(slots)
 
 
+@dataclass(frozen=True)
+class Weights:
+    """Model parameters as read-only executor state: one plain DistTensor
+    per parameter leaf, named by its tree path."""
+
+    treedef: Any
+    tensors: tuple
+
+    @classmethod
+    def of(cls, params) -> "Weights":
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+        return cls(treedef, tuple(
+            DistTensor("w:" + jax.tree_util.keystr(path, simple=True,
+                                                   separator="/"),
+                       x.shape, dtype=x.dtype)
+            for path, x in leaves))
+
+    def tree(self, leaves):
+        """The parameter tree from the node args bound to ``tensors``."""
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)
+
+    def state(self, params) -> dict:
+        """``Executor.init_state`` overrides binding ``params``."""
+        return {t.name: x
+                for t, x in zip(self.tensors, jax.tree.leaves(params))}
+
+
 def _slot_params(params, gi: int, pi: int):
     if gi < 0:
         return params[f"tail{pi}"]["layer"]
@@ -481,21 +512,21 @@ def _guard_graph_serving(cfg: ModelConfig) -> None:
             f"jit path (launch/serve.py falls back automatically)")
 
 
-def _embed_node(cfg: ModelConfig, ctx: ShardCtx, params):
-    def embed(tokens_t, h_t):
-        return embed_tokens(params, tokens_t, cfg, ctx)
+def _embed_node(cfg: ModelConfig, ctx: ShardCtx, w: Weights):
+    def embed(tokens_t, h_t, *leaves):
+        return embed_tokens(w.tree(leaves), tokens_t, cfg, ctx)
     return embed
 
 
-def _attn_layer_node(cfg: ModelConfig, ctx: ShardCtx, params,
+def _attn_layer_node(cfg: ModelConfig, ctx: ShardCtx, w: Weights,
                      slot: CacheSlot):
     gi, pi, kind = slot.group, slot.part, slot.kind
 
-    def layer(h_t, kv, pos):
+    def layer(h_t, kv, pos, *leaves):
         # the solver's layout choice arrives on the RecordArray; re-derive
         # the config under it so the kernel code is layout-polymorphic
         lcfg = cfg.with_(kv_layout=kv.layout)
-        p = _slot_params(params, gi, pi)
+        p = _slot_params(w.tree(leaves), gi, pi)
         h2, store = layer_decode(p, h_t, kind, lcfg, ctx,
                                  cache=kv.data, pos=pos)
         return h2, RecordArray(store, kv.spec, kv.layout)
@@ -503,12 +534,12 @@ def _attn_layer_node(cfg: ModelConfig, ctx: ShardCtx, params,
     return layer
 
 
-def _state_layer_node(cfg: ModelConfig, ctx: ShardCtx, params,
+def _state_layer_node(cfg: ModelConfig, ctx: ShardCtx, w: Weights,
                       slot: CacheSlot):
     gi, pi, kind = slot.group, slot.part, slot.kind
 
-    def layer(h_t, s0, s1, pos):
-        p = _slot_params(params, gi, pi)
+    def layer(h_t, s0, s1, pos, *leaves):
+        p = _slot_params(w.tree(leaves), gi, pi)
         h2, (n0, n1) = layer_decode(p, h_t, kind, cfg, ctx,
                                     cache=(s0, s1), pos=pos)
         return h2, n0, n1
@@ -516,8 +547,9 @@ def _state_layer_node(cfg: ModelConfig, ctx: ShardCtx, params,
     return layer
 
 
-def _head_node(cfg: ModelConfig, ctx: ShardCtx, params):
-    def head(h_t, tokens_t, pos, active):
+def _head_node(cfg: ModelConfig, ctx: ShardCtx, w: Weights):
+    def head(h_t, tokens_t, pos, active, *leaves):
+        params = w.tree(leaves)
         hn = norm_apply(params["final"], h_t, cfg, "ln")
         logits = lm_logits(params, hn, cfg, ctx)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -533,7 +565,8 @@ class DecodeGraph:
     State layout: ``tokens``/``pos``/``active`` are (B,) per-slot vectors
     (continuous batching: every batch slot sits at its own depth; inactive
     slots keep their token and don't advance), ``h`` is the (B, d_model)
-    residual scratch, and each CacheSlot contributes its cache tensors."""
+    residual scratch, each CacheSlot contributes its cache tensors, and
+    ``weights`` binds the parameters (``weights.state(params)``)."""
 
     graph: Graph
     tokens: DistTensor
@@ -541,6 +574,7 @@ class DecodeGraph:
     active: DistTensor
     h: DistTensor
     slots: tuple
+    weights: Weights
 
 
 @dataclass(frozen=True)
@@ -557,6 +591,7 @@ class PrefillGraph:
     hlast: DistTensor
     first: DistTensor
     slots: tuple
+    weights: Weights
 
 
 def cache_state_overrides(cfg: ModelConfig, slots: tuple, caches) -> dict:
@@ -603,20 +638,22 @@ def make_decode_graph(cfg: ModelConfig, params, *, batch: int, max_seq: int,
     active = DistTensor("active", (batch,), dtype=jnp.bool_)
     h = DistTensor("h", (batch, cfg.d_model), dtype=cfg.compute_jdtype)
     slots = serving_cache_slots(cfg, batch, max_seq, tp)
+    w = Weights.of(params)
     g = Graph(name=f"decode_{cfg.name}")
-    g.then(_embed_node(cfg, ctx, params), args=(tokens, h), writes=(1,))
+    g.then(_embed_node(cfg, ctx, w), args=(tokens, h, *w.tensors),
+           writes=(1,))
     for slot in slots:
         if slot.kind in ("A", "L"):
             kv, = slot.tensors
-            g.then(_attn_layer_node(cfg, ctx, params, slot),
-                   args=(h, kv, pos), writes=(0, 1))
+            g.then(_attn_layer_node(cfg, ctx, w, slot),
+                   args=(h, kv, pos, *w.tensors), writes=(0, 1))
         else:
             s0, s1 = slot.tensors
-            g.then(_state_layer_node(cfg, ctx, params, slot),
-                   args=(h, s0, s1, pos), writes=(0, 1, 2))
-    g.then(_head_node(cfg, ctx, params),
-           args=(h, tokens, pos, active), writes=(1, 2))
-    out = DecodeGraph(g, tokens, pos, active, h, slots)
+            g.then(_state_layer_node(cfg, ctx, w, slot),
+                   args=(h, s0, s1, pos, *w.tensors), writes=(0, 1, 2))
+    g.then(_head_node(cfg, ctx, w),
+           args=(h, tokens, pos, active, *w.tensors), writes=(1, 2))
+    out = DecodeGraph(g, tokens, pos, active, h, slots, w)
     _SERVE_GRAPH_CACHE[key] = out
     return out
 
@@ -646,8 +683,10 @@ def make_prefill_graph(cfg: ModelConfig, params, *, prompt_len: int,
     first = DistTensor("first", (1,), dtype=jnp.int32)
     slots = serving_cache_slots(cfg, 1, max_seq, tp)
     flat = tuple(t for slot in slots for t in slot.tensors)
+    w = Weights.of(params)
 
-    def body(h_, hl_, *cache_vals):
+    def body(h_, hl_, *rest):
+        params = w.tree(rest[len(flat):])
         positions = jnp.arange(h_.shape[1], dtype=jnp.int32)
         hh = ctx.constrain(h_, P(ctx.ba, None, None))
         hh, _, raw = decoder_pass(params, hh, cfg, ctx,
@@ -668,15 +707,16 @@ def make_prefill_graph(cfg: ModelConfig, params, *, prompt_len: int,
                 outs.extend(store)
         return (hh[:, -1], *outs)
 
-    def head(hl_, first_):
-        logits = lm_logits(params, hl_, cfg, ctx)
+    def head(hl_, first_, *leaves):
+        logits = lm_logits(w.tree(leaves), hl_, cfg, ctx)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     g = Graph(name=f"prefill_{cfg.name}_s{prompt_len}")
-    g.then(_embed_node(cfg, ctx, params), args=(prompt, hseq), writes=(1,))
-    g.then(body, args=(hseq, hlast, *flat),
+    g.then(_embed_node(cfg, ctx, w), args=(prompt, hseq, *w.tensors),
+           writes=(1,))
+    g.then(body, args=(hseq, hlast, *flat, *w.tensors),
            writes=tuple(range(1, 2 + len(flat))))
-    g.then(head, args=(hlast, first), writes=(1,))
-    out = PrefillGraph(g, prompt, hseq, hlast, first, slots)
+    g.then(head, args=(hlast, first, *w.tensors), writes=(1,))
+    out = PrefillGraph(g, prompt, hseq, hlast, first, slots, w)
     _SERVE_GRAPH_CACHE[key] = out
     return out

@@ -20,6 +20,7 @@ import numpy as np
 
 import repro.configs as configs
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.data import SyntheticLM
 from repro.launch import steps as S
 from repro.launch.mesh import make_mesh
@@ -59,6 +60,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     mesh = None
     if args.mesh:

@@ -122,16 +122,13 @@ class ParamTree:
         return ParamTree(self._next(), self.dtype)
 
 
-def stack_layers(trees: Sequence[tuple[dict, dict]]) -> tuple[dict, dict]:
-    """Stack per-layer (params, specs) into scan-ready stacked params with a
-    leading 'layer' logical axis."""
-    params = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
-                          *[t[0] for t in trees])
-    specs = jax.tree.map(
-        lambda axes: ("layer", *axes), trees[0][1],
+def stack_specs(specs: dict) -> dict:
+    """One layer's logical-spec tree with the leading 'layer' axis of
+    scan-ready stacked params."""
+    return jax.tree.map(
+        lambda axes: ("layer", *axes), specs,
         is_leaf=lambda x: isinstance(x, tuple) and all(
             a is None or isinstance(a, str) for a in x))
-    return params, specs
 
 
 # ---------------------------------------------------------------------------

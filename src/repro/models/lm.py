@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from .blocks import (ShardCtx, init_layer, init_norm, layer_decode,
                      layer_forward, make_layer_cache, norm_apply)
-from .common import ParamTree, count_params, stack_layers
+from .common import ParamTree, count_params, stack_specs
 from .config import ModelConfig
 
 NEG_INF = -1e30
@@ -36,19 +36,41 @@ NEG_INF = -1e30
 
 def _init_group_stack(pt: ParamTree, cfg: ModelConfig, pattern, n_groups: int,
                       tp: int, *, cross: bool, name: str) -> None:
-    trees = []
-    for _ in range(n_groups):
-        g = pt.child()
+    specs = []
+
+    def init_group(key):
+        g = ParamTree(key, pt.dtype)
         for i, kind in enumerate(pattern):
             init_layer(g, cfg, kind, tp, cross=cross, name=f"p{i}")
-        trees.append((g.params, g.specs))
-    params, specs = stack_layers(trees)
-    pt.params[name] = params
-    pt.specs[name] = specs
+        specs.append(g.specs)
+        return g.params
+
+    # one vmapped trace draws every group straight into the scan-ready
+    # stacked arrays (the keys are those pt.child() would hand out), so
+    # no per-group copy is ever stacked
+    keys = jnp.stack([pt._next() for _ in range(n_groups)])
+    pt.params[name] = jax.vmap(init_group)(keys)
+    pt.specs[name] = stack_specs(specs[0])
 
 
 def init_lm(cfg: ModelConfig, key: jax.Array, tp: int = 1):
-    """-> (params, logical-spec tree)."""
+    """-> (params, logical-spec tree).
+
+    The params come out of one jitted program, so the device holds only
+    the final tree and no intermediate of the init (at published widths
+    a per-layer copy of the stacked weights would double their
+    footprint)."""
+    specs = []   # strings, not arrays: recorded by an abstract trace
+    jax.eval_shape(lambda k: specs.append(_build_lm(cfg, k, tp)[1]), key)
+    return _init_params(cfg, key, tp), specs[0]
+
+
+@partial(jax.jit, static_argnums=(0, 2))
+def _init_params(cfg: ModelConfig, key: jax.Array, tp: int):
+    return _build_lm(cfg, key, tp)[0]
+
+
+def _build_lm(cfg: ModelConfig, key: jax.Array, tp: int):
     pt = ParamTree(key, dtype=cfg.param_jdtype)
     Vp = cfg.padded_vocab(tp)
     d = cfg.d_model

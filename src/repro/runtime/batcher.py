@@ -134,9 +134,12 @@ class Batcher:
         self._exec_opts = dict(executor_opts or {})
         self.dg = make_decode_graph(cfg, params, batch=batch,
                                     max_seq=max_seq, mesh=mesh)
+        # the weights ride as read-only executor state
+        self._weights = self.dg.weights.state(params)
         self.executor = Executor(self.dg.graph, mesh=mesh,
-                                 **self._exec_opts)
-        self.state = self.executor.init_state()
+                                 **{"tune_inputs": self._weights,
+                                    **self._exec_opts})
+        self.state = self.executor.init_state(**self._weights)
         self.slots: list = [None] * batch
         self.queue: deque = deque()
         self.retired: list = []
@@ -209,7 +212,8 @@ class Batcher:
 
     def _prefill_state(self, prompt: np.ndarray):
         pg, exp = self._prefill_for(len(prompt))
-        pst = exp.init_state(prompt=jnp.asarray(prompt, jnp.int32)[None])
+        pst = exp.init_state(prompt=jnp.asarray(prompt, jnp.int32)[None],
+                             **self._weights)
         return pg, exp, exp(pst)
 
     def _prefill_ahead(self) -> None:
@@ -384,7 +388,7 @@ class Batcher:
         residue."""
         live = [(slot, req) for slot, req in enumerate(self.slots)
                 if req is not None]
-        self.state = self.executor.init_state()
+        self.state = self.executor.init_state(**self._weights)
         for slot, req in live:
             self._admit(req, slot)
 

@@ -94,12 +94,8 @@ def device_assortment() -> tuple:
     for d in jax.devices():
         k = (d.platform, str(getattr(d, "device_kind", "")))
         counts[k] = counts.get(k, 0) + 1
-    try:
-        procs = int(jax.process_count())
-    except Exception:   # very old jax: single-process by definition
-        procs = 1
     return (tuple(sorted((p, kind, n) for (p, kind), n in counts.items())),
-            procs)
+            jax.process_count())
 
 
 def _validate(payload: Any, key: str, schema: int = SCHEMA_VERSION) -> dict:

@@ -204,8 +204,11 @@ def test_region_eikonal_golden():
     mask0 = jnp.zeros((nx, ny), bool).at[nx // 2, ny // 2].set(True)
     r = _region_cost(ex, ex.init_state(phi=phi0, mask=mask0))
     assert r["flops"] == 0          # godunov update: min/sqrt, no dots
-    ideal = 2 * nx * ny * 4         # read phi, write phi
-    assert ideal <= r["bytes"] <= 6 * ideal
+    ideal = 2 * nx * ny * 4 + nx * ny   # read phi + bool mask, write phi
+    # the transmissive halo is assembled from edge slices by three
+    # phi-sized concatenates (each charged result + operands), and the
+    # stencil fusion reads those haloed copies: ~6x ideal, hence 8x
+    assert ideal <= r["bytes"] <= 8 * ideal
     assert r["collective_link_bytes"] == 0
 
 

@@ -10,6 +10,24 @@ from repro.core import (Boundary, Layout, RecordArray, pad_boundary_only,
                         relayout)
 
 
+# -- interpret mode follows the platform ----------------------------------------
+
+@pytest.mark.parametrize("backend,requested,want", [
+    ("cpu", None, True), ("cpu", False, False), ("cpu", True, True),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+])
+def test_interpret_mode_follows_platform(monkeypatch, backend, requested,
+                                         want):
+    from repro.kernels import interpret_mode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="TPU"):
+            interpret_mode(requested)
+    else:
+        assert interpret_mode(requested) is want
+
+
 # -- saxpy --------------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [128, 1000, 4096])

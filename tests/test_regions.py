@@ -94,9 +94,7 @@ def test_run_fused_shares_one_trace_across_steps():
         ex.run(ex.init_state(u=jnp.ones((8, 8))), steps=steps)
     assert ex.cache_stats()["trace_events"] == base
     (key,) = [k for k in ex._cache.executables if k[0] == "fused"]
-    jit_fn = ex._cache.executables[key].jit_fn
-    if hasattr(jit_fn, "_cache_size"):
-        assert jit_fn._cache_size() == 1
+    assert ex._cache.executables[key].jit_fn._cache_size() == 1
 
 
 def test_run_fused_values_match_stepwise_calls():

@@ -294,3 +294,36 @@ def test_encdec_archs_rejected_by_graph_builders():
     params, _ = lm.init_lm(cfg, jax.random.PRNGKey(0), tp=1)
     with pytest.raises(NotImplementedError):
         steps.make_decode_graph(cfg, params, batch=1, max_seq=8)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_serving_regions_embed_no_weights(which):
+    """The serving graphs take the weights as executor inputs: no region
+    executable may hold a constant above 1 MiB (a closed-over weight
+    would be inlined as one — at published widths, gigabytes of HLO
+    literals).  The vocab is widened so the embedding alone is 2 MiB."""
+    from repro.analysis.hlo import HloCostModel, _shape_bytes
+    from repro.core import Executor
+
+    cfg = configs.get_smoke("qwen1_5_4b").with_(vocab_size=8192)
+    params, _ = lm.init_lm(cfg, jax.random.PRNGKey(0), tp=1)
+    assert params["embed"].nbytes > 1 << 20
+    if which == "decode":
+        g = steps.make_decode_graph(cfg, params, batch=2, max_seq=16)
+        overrides = {}
+    else:
+        g = steps.make_prefill_graph(cfg, params, prompt_len=8, max_seq=16)
+        overrides = {"prompt": jnp.ones((1, 8), jnp.int32)}
+    ex = Executor(g.graph, donate=False)
+    state = ex.init_state(**overrides, **g.weights.state(params))
+    for i, region in enumerate(ex._regions):
+        model = HloCostModel(ex.region_hlo(state, i))
+        big = [(op.name, op.result_sig) for comp in model.comps.values()
+               for op in comp.ops
+               if op.opcode == "constant"
+               and _shape_bytes(op.result_sig) > 1 << 20]
+        assert not big, f"region {i} embeds constants: {big}"
+    # the weights are read-only state: passed in, never donated or copied
+    out = ex(state)
+    for t in g.weights.tensors:
+        assert out[t.name] is state[t.name]

@@ -30,8 +30,9 @@ decisions* (DESIGN.md §2/§4), which this executor makes explicitly:
   step counts share one trace) and the non-fused path loops over cached
   region executables with no eager relayout dispatch while consecutive
   iterations agree on layout.  ``Executor(regions=False)`` is the
-  per-segment-dispatch escape hatch (and the baseline
-  ``benchmarks/dispatch_overhead.py`` measures against);
+  per-segment-dispatch escape hatch.  On the chip the benchmark's
+  ``dispatch_ms.euler`` reads the host time of each executable call (the
+  ``ripple.dispatch`` spans, see ``repro.tracing``);
 * a segment with partitioned tensors is lowered through one ``shard_map``
   — the paper's one-node-per-partition becomes one program per shard;
 * ``concurrent_padded_access`` + ``overlap=True`` splits the stencil into
@@ -91,6 +92,7 @@ import enum as enum_lib
 import functools
 import hashlib
 import math
+import re
 import sys
 import threading
 import types
@@ -108,6 +110,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from .. import tracing
 from ..runtime.faults import (HostTimeoutError, TransientError,
                               trip as _fault_trip)
 from ..tuning.tiles import tile_scope
@@ -166,9 +169,10 @@ def _apply_halo(data: jax.Array, t: DistTensor, mesh: Optional[Mesh]) -> jax.Arr
     entries = _halo_plan(t, mesh)
     if not entries:
         return data
-    return halo_lib.exchange_multi(
-        data, _halo_axes(entries),
-        boundary=t.boundary, constant=t.boundary_constant)
+    with jax.named_scope("halo_exchange"):
+        return halo_lib.exchange_multi(
+            data, _halo_axes(entries),
+            boundary=t.boundary, constant=t.boundary_constant)
 
 
 def _slice(x, axis, start, size):
@@ -300,9 +304,10 @@ class _AsyncRun:
             jax.block_until_ready(leaves)
             _fault_trip("executor.host", detail=f"region{region_index}")
             if fn is not None:
-                fn(*vals)
+                with tracing.span("ripple.host", region=region_index):
+                    fn(*vals)
 
-        fut = _host_pool().submit(task)
+        fut = _host_pool().submit(tracing.carry(task))
         self._prev = fut
         self.tasks.append((region_index, fut))
 
@@ -322,7 +327,8 @@ class _AsyncRun:
     def _wait_oldest(self) -> None:
         region_index, fut = self.tasks[0]
         try:
-            self._timed_result(region_index, fut)
+            with tracing.span("ripple.host_wait", reason="inflight"):
+                self._timed_result(region_index, fut)
         except _HostTaskCancelled:
             pass
         self.tasks.pop(0)
@@ -398,7 +404,9 @@ class HaloTransfer:
     (``None`` — a local boundary fill, no transfer); ``phase`` is when the
     send is issued (1 = up-front edge strips, 2+ = extended-edge corner
     hops); ``overlapped`` marks blocks whose flight is hidden behind the
-    node's interior program."""
+    node's interior program.  ``pairs`` counts the shards of one mesh-axis
+    group that send the block: all ``n`` on a periodic axis, ``n - 1``
+    otherwise, where the edge shard fills its block locally."""
 
     segment: int
     node: str
@@ -409,6 +417,7 @@ class HaloTransfer:
     width: int
     overlapped: bool
     nbytes: int = 0                      # per-shard block payload size
+    pairs: int = 0                       # ppermute source-target pairs
 
     def describe(self) -> str:
         where = "+".join(f"{'-' if s == 'low' else '+'}d{d}"
@@ -870,7 +879,8 @@ def _node_sig(node: Node):
 def _graph_sig(g: Graph):
     levels = tuple(tuple(_node_sig(n) for n in level) for level in g.levels)
     cond = None if g.condition is None else _fn_sig(g.condition)
-    return ("graph", levels, cond)
+    # the name scopes the graph's nodes lower under carry its name
+    return ("graph", g.name, levels, cond)
 
 
 def _segments_sig(segments):
@@ -896,9 +906,10 @@ def _mesh_sig(mesh: Optional[Mesh]):
 
 
 def plan_signature(executor: "Executor") -> tuple:
-    """Structural identity of a compiled plan: graph structure (node
-    kinds, args, function code + closures — NOT auto-generated node
-    names), tensor shapes/dtypes/layouts, mesh, schedule mode, per-
+    """Structural identity of a compiled plan: graph names and structure
+    (node kinds, args, function code + closures — NOT auto-generated
+    node names; the graph names are in the executables' names and name
+    scopes), tensor shapes/dtypes/layouts, mesh, schedule mode, per-
     segment layout decisions, kernel tile overrides, and donation.  Two
     executors with equal signatures compute identical values for
     identical inputs, so their compiled region executables are
@@ -911,7 +922,8 @@ def plan_signature(executor: "Executor") -> tuple:
     the per-segment decision tuples, but a FORCED per-segment override
     never aliases an unforced plan."""
     plan = executor.plan
-    return ("ripple-plan-v3", executor.schedule, executor.donate,
+    return ("ripple-plan-v3", executor.graph.name, executor.schedule,
+            executor.donate,
             _mesh_sig(executor.mesh), _segments_sig(executor._segments),
             tuple(tuple(sorted((n, l.name) for n, l in seg.items()))
                   for seg in plan.per_segment),
@@ -1087,8 +1099,9 @@ class Executor:
     of device/loop segments become one jitted executable each, with the
     boundary relayouts traced inside, cached process-wide by plan
     signature.  ``regions=False`` falls back to per-segment dispatch with
-    eager Python relayout glue (the pre-region behavior, and the baseline
-    ``benchmarks/dispatch_overhead.py`` measures against).
+    eager Python relayout glue (the pre-region behavior).  The host time
+    of each executable call is the ``ripple.dispatch`` span, which the
+    benchmark's ``dispatch_ms.euler`` reads on the chip.
 
     Both schedules (and both region modes) produce bitwise-identical
     state for any valid graph; the DAG schedule just gives XLA more to
@@ -1179,6 +1192,10 @@ class Executor:
         self.tensors = graph.all_tensors()
         self.results = graph.all_results()
         self.dag = schedule_lib.build_dag(graph)
+        # device-side names: each node lowers under its graph/label name
+        # scope, and the executables are named after the graph
+        self._scopes = graph.node_scopes()
+        self._exe_name = "ripple_" + re.sub(r"\W", "_", graph.name)
         # the user's configured operating point — the top of the ladder
         # (level 0); _apply_ladder_level restores toward these
         self._cfg_schedule = schedule
@@ -1359,6 +1376,15 @@ class Executor:
                      else t).validate_mesh(self.mesh)
         self._overlap_decisions: dict[str, _OverlapDecision] = {}
         self._collect_halo_schedule()
+        # bytes one pass sends through collectives, per chip on average:
+        # each scheduled block of a device segment, from each shard that
+        # sends it (a device loop's trip count is known only on the
+        # device, so its blocks are not counted)
+        self._halo_pass_bytes = sum(
+            h.nbytes * h.pairs / self.mesh.shape[h.mesh_axis]
+            for h in self.plan.halo_transfers
+            if h.mesh_axis is not None
+            and self._segments[h.segment][0] == "device")
         # region compiler: segment runs -> fused executables, cached
         # process-wide by plan signature
         self._regions = schedule_lib.group_regions(
@@ -1433,12 +1459,15 @@ class Executor:
                     for phase, bkey, shape in halo_lib.schedule_blocks(
                             shard, axes):
                         last, _side = bkey[-1]
+                        ax = entries[last].mesh_axis
+                        pairs = 0 if ax is None else mesh.shape[ax]
+                        if eff_t.boundary is not halo_lib.Boundary.PERIODIC:
+                            pairs = max(pairs - 1, 0)
                         self.plan.halo_transfers.append(HaloTransfer(
                             si, node.name, t.name, phase,
                             tuple((entries[j].dim, s) for j, s in bkey),
-                            entries[last].mesh_axis, entries[last].width,
-                            overlapped,
-                            nbytes=math.prod(shape) * itemsize))
+                            ax, entries[last].width, overlapped,
+                            nbytes=math.prod(shape) * itemsize, pairs=pairs))
 
     # -- layout plumbing ---------------------------------------------------
     def _eff_in(self, t: DistTensor, layouts: dict[str, Layout]) -> DistTensor:
@@ -1476,13 +1505,15 @@ class Executor:
             cur = self._state_layouts.get(name, t.layout)
             if cur is lay:
                 continue
-            arr = relayout(RecordArray(state[name], t.spec, cur), lay)
-            data = arr.data
-            self._state_layouts[name] = lay
-            self.eager_relayouts += 1
-            if self.mesh is not None:
-                data = jax.device_put(data,
-                                      self._eff(t).sharding(self.mesh))
+            with tracing.span("ripple.relayout", tensor=name,
+                              bytes=state[name].nbytes):
+                arr = relayout(RecordArray(state[name], t.spec, cur), lay)
+                data = arr.data
+                self._state_layouts[name] = lay
+                self.eager_relayouts += 1
+                if self.mesh is not None:
+                    data = jax.device_put(data,
+                                          self._eff(t).sharding(self.mesh))
             state[name] = data
         return state
 
@@ -1499,6 +1530,10 @@ class Executor:
         Record tensors are materialized directly in the layout the solver
         chose for their first consuming segment; a RecordArray override in
         another layout is relayouted on the way in."""
+        with tracing.span("ripple.init_state", graph=self.graph.name):
+            return self._init_state(overrides)
+
+    def _init_state(self, overrides: dict) -> dict[str, Any]:
         self._state_layouts = dict(self.plan.initial)
         state: dict[str, Any] = {}
         for name, t in self.tensors.items():
@@ -1720,10 +1755,13 @@ class Executor:
                 entries[d].width if d in entries else 0,
                 entries[d].mesh_axis if d in entries else None)
                 for d in dims]
-            blocks = (halo_lib.exchange_blocks(
-                data, axes, boundary=t.boundary,
-                constant=t.boundary_constant)
-                if any(ax.width for ax in axes) else {(): data})
+            if any(ax.width for ax in axes):
+                with jax.named_scope("halo_exchange"):
+                    blocks = halo_lib.exchange_blocks(
+                        data, axes, boundary=t.boundary,
+                        constant=t.boundary_constant)
+            else:
+                blocks = {(): data}
             preps.append(("tensor", (t, dims, axes, blocks)))
 
         def ranges_for(variant, dims, axes, blocks):
@@ -1754,8 +1792,10 @@ class Executor:
                     vals.append(payload)
                     continue
                 t, dims, axes, blocks = payload
-                data = halo_lib.assemble_region(
-                    blocks, axes, ranges_for(variant, dims, axes, blocks))
+                with jax.named_scope("halo_assemble"):
+                    data = halo_lib.assemble_region(
+                        blocks, axes,
+                        ranges_for(variant, dims, axes, blocks))
                 vals.append(t.wrap(data) if t.is_record else data)
             out = node.fn(*vals)
             if len(write_tensors) == 1:
@@ -1784,7 +1824,8 @@ class Executor:
                      strip_outs[(k, "high")][wi]],
                     axis=wt_eff.storage_axis(d))
 
-            state[wt.name] = stitch(0)
+            with jax.named_scope("halo_stitch"):
+                state[wt.name] = stitch(0)
 
     def _lower_reduce(self, node: Node, state: dict, sharded: bool,
                       layouts: dict[str, Layout]) -> None:
@@ -1794,8 +1835,11 @@ class Executor:
             data = self._eff_in(t, layouts).wrap(data).field(field)
         local = node.reducer.local(data)
         if sharded:
-            axes = tuple({ax for ax in t.partition if ax is not None
-                          and self.mesh.shape[ax] > 1})
+            # in the partition's order: a set's would follow the process's
+            # string hashing, and so would the program and its cache key
+            axes = tuple(dict.fromkeys(
+                ax for ax in t.partition
+                if ax is not None and self.mesh.shape[ax] > 1))
             if axes:
                 local = _combine_over_axes(local, axes,
                                            node.reducer.combine)
@@ -1809,32 +1853,40 @@ class Executor:
             # same input snapshot, then merge (XLA runs them in parallel).
             snapshot = dict(state)
             for node in level:
-                if node.kind == "split":
-                    tmp = dict(snapshot)
-                    self._lower_split(node, tmp, sharded, layouts)
-                    for k, v in tmp.items():
-                        if k not in snapshot or v is not snapshot[k]:
-                            state[k] = v
-                elif node.kind == "reduce":
-                    tmp = dict(snapshot)
-                    self._lower_reduce(node, tmp, sharded, layouts)
-                    state[node.result.name] = tmp[node.result.name]
-                elif node.kind == "op":
-                    tmp = dict(snapshot)
-                    vals = self._resolve_args(node, tmp, sharded, layouts)
-                    writes = node.default_writes()
-                    wt = []
-                    for i in writes:
-                        a = node.args[i]
-                        wt.append(a.tensor if isinstance(a, TensorArg) else a)
-                    out = node.fn(*vals) if node.fn is not None else None
-                    if wt:
-                        self._store_writes(node, tmp, wt, out, layouts)
-                        for t in wt:
-                            state[t.name] = tmp[t.name]
-                else:
-                    raise ValueError(f"unexpected node kind {node.kind}")
+                with jax.named_scope(self._scopes.get(id(node), node.name)):
+                    self._lower_node(node, snapshot, state, sharded,
+                                     layouts)
         return state
+
+    def _lower_node(self, node: Node, snapshot: dict, state: dict,
+                    sharded: bool, layouts: dict[str, Layout]) -> None:
+        """Lower one node against the level's input ``snapshot``, merging
+        what it writes into ``state``."""
+        if node.kind == "split":
+            tmp = dict(snapshot)
+            self._lower_split(node, tmp, sharded, layouts)
+            for k, v in tmp.items():
+                if k not in snapshot or v is not snapshot[k]:
+                    state[k] = v
+        elif node.kind == "reduce":
+            tmp = dict(snapshot)
+            self._lower_reduce(node, tmp, sharded, layouts)
+            state[node.result.name] = tmp[node.result.name]
+        elif node.kind == "op":
+            tmp = dict(snapshot)
+            vals = self._resolve_args(node, tmp, sharded, layouts)
+            writes = node.default_writes()
+            wt = []
+            for i in writes:
+                a = node.args[i]
+                wt.append(a.tensor if isinstance(a, TensorArg) else a)
+            out = node.fn(*vals) if node.fn is not None else None
+            if wt:
+                self._store_writes(node, tmp, wt, out, layouts)
+                for t in wt:
+                    state[t.name] = tmp[t.name]
+        else:
+            raise ValueError(f"unexpected node kind {node.kind}")
 
     # -- loop (conditional subgraph) lowering --------------------------------
     def _sub_executor(self, i: int) -> "Executor":
@@ -1908,10 +1960,11 @@ class Executor:
         constraint mirrors what the eager path's device_put enforced)."""
         for name, src, dst in conv:
             t = self.tensors[name]
-            data = relayout_data(state[name], t.spec, src, dst)
-            if self.mesh is not None:
-                data = lax.with_sharding_constraint(
-                    data, self._eff_in(t, layouts).sharding(self.mesh))
+            with jax.named_scope("relayout"):
+                data = relayout_data(state[name], t.spec, src, dst)
+                if self.mesh is not None:
+                    data = lax.with_sharding_constraint(
+                        data, self._eff_in(t, layouts).sharding(self.mesh))
             state[name] = data
         return state
 
@@ -1970,26 +2023,29 @@ class Executor:
 
         def region_call(donated, kept, consts):
             cache_entry.trace_events += 1   # Python body runs per trace only
-            state = {**donated, **kept, **consts}
-            for si, conv, layouts in chain:
-                state = self._traced_convert(dict(state), conv, layouts)
-                kind, payload = self._segments[si]
-                if kind == "device":
-                    if sharded:
-                        specs = self._state_specs(state, layouts)
-                        fn = jax.shard_map(
-                            partial(self._lower_levels, payload,
-                                    sharded=True, layouts=layouts),
-                            mesh=self.mesh, in_specs=(specs,),
-                            out_specs=specs, check_vma=False)
-                        state = fn(state)
-                    else:
-                        state = self._lower_levels(payload, state, False,
-                                                   layouts)
-                else:  # 'loop'
-                    state = self._lower_loop(payload, si, state)
-            return {k: v for k, v in state.items() if k not in read_only}
+            with tracing.span("ripple.trace", graph=self.graph.name,
+                              region=region.index):
+                state = {**donated, **kept, **consts}
+                for si, conv, layouts in chain:
+                    state = self._traced_convert(dict(state), conv, layouts)
+                    kind, payload = self._segments[si]
+                    if kind == "device":
+                        if sharded:
+                            specs = self._state_specs(state, layouts)
+                            fn = jax.shard_map(
+                                partial(self._lower_levels, payload,
+                                        sharded=True, layouts=layouts),
+                                mesh=self.mesh, in_specs=(specs,),
+                                out_specs=specs, check_vma=False)
+                            state = fn(state)
+                        else:
+                            state = self._lower_levels(payload, state, False,
+                                                       layouts)
+                    else:  # 'loop'
+                        state = self._lower_loop(payload, si, state)
+                return {k: v for k, v in state.items() if k not in read_only}
 
+        region_call.__name__ = f"{self._exe_name}_region{region.index}"
         jfn = jax.jit(region_call,
                       donate_argnums=(0,) if self.donate else ())
         tile_config = self._tile_config
@@ -2028,29 +2084,30 @@ class Executor:
             return fn.jit_fn.lower(*args).compile().as_text()
 
     # -- segment compilation (regions=False per-segment dispatch) -----------
-    def _device_fn(self, levels) -> Callable:
+    def _device_fn(self, levels, seg: int) -> Callable:
         sharded = self._sharded
 
         def body(state):
             return self._lower_levels(levels, state, sharded,
                                       dict(self._state_layouts))
 
-        if not sharded:
-            return jax.jit(body, donate_argnums=0 if self.donate else ())
-
-        # specs must cover exactly the state dict; build lazily per call
         def call(state):
+            if not sharded:
+                return body(state)
+            # specs must cover exactly the state dict; build lazily per call
             specs = self._state_specs(state, self._state_layouts)
             fn = jax.shard_map(body, mesh=self.mesh, in_specs=(specs,),
                                out_specs=specs, check_vma=False)
             return fn(state)
 
+        call.__name__ = f"{self._exe_name}_segment{seg}"
         return jax.jit(call, donate_argnums=0 if self.donate else ())
 
     def _loop_fn(self, sub: Graph, seg: int) -> Callable:
         def call(state):
             return self._lower_loop(sub, seg, state)
 
+        call.__name__ = f"{self._exe_name}_segment{seg}"
         return jax.jit(call, donate_argnums=0 if self.donate else ())
 
     # -- public execution -----------------------------------------------------
@@ -2076,15 +2133,22 @@ class Executor:
             return None
         return _AsyncRun(self.donate, self.host_timeout)
 
+    def _path(self) -> str:
+        return "regions" if self.regions_enabled else "segments"
+
     def __call__(self, state: dict) -> dict:
-        with self._layout_epoch():
+        with tracing.span("ripple.run", new_run=True, graph=self.graph.name,
+                          steps=1, path=self._path()), \
+                self._layout_epoch():
             ctx = self._async_ctx()
             try:
                 state = self._pass_once(dict(state), ctx)
                 state = self._restore_initial_layouts(dict(state))
                 if ctx is not None:
-                    ctx.drain()
+                    with tracing.span("ripple.host_wait", reason="drain"):
+                        ctx.drain()
                 self._note_clean_pass()
+                tracing.count("halo.bytes", self._halo_pass_bytes)
                 return state
             except BaseException as exc:
                 if ctx is not None:
@@ -2123,7 +2187,9 @@ class Executor:
                 _fault_trip("executor.region",
                             detail=f"region{region.index}")
                 fn, exit_layouts = self._region_executable(region)
-                state = fn(state)
+                with tracing.span("ripple.dispatch", graph=self.graph.name,
+                                  region=region.index):
+                    state = fn(state)
                 self._state_layouts.update(exit_layouts)
             elif region.kind == "host":
                 si = region.start
@@ -2136,25 +2202,29 @@ class Executor:
                         if node.args else []
                     ctx.submit(region.index, node.fn, vals)
                     continue
-                if ctx is not None:
-                    ctx.drain()   # barrier: side-effect order vs pool
-                jax.block_until_ready(jax.tree_util.tree_leaves(state))
+                with tracing.span("ripple.host_wait", reason="barrier"):
+                    if ctx is not None:
+                        ctx.drain()   # barrier: side-effect order vs pool
+                    jax.block_until_ready(jax.tree_util.tree_leaves(state))
                 _fault_trip("executor.host",
                             detail=f"region{region.index}")
                 if node.fn is not None:
                     vals = self._resolve_args(
                         node, state, False, self._state_layouts) \
                         if node.args else []
-                    node.fn(*vals)
+                    with tracing.span("ripple.host", region=region.index):
+                        node.fn(*vals)
             else:  # host_loop
                 si = region.start
                 state = self._apply_segment_layouts(dict(state), si)
                 if ctx is not None:
-                    ctx.drain()   # the sub-executor writes state eagerly
+                    # the sub-executor writes state eagerly
+                    with tracing.span("ripple.host_wait", reason="host_loop"):
+                        ctx.drain()
                 sub_graph: Graph = self._segments[si][1]
                 sub = self._sub_executor(si)
                 # while semantics: check before the first iteration too
-                while bool(jax.device_get(sub_graph.condition(state))):
+                while _read_condition(sub_graph, state):
                     state = sub(state)
         return state
 
@@ -2169,29 +2239,35 @@ class Executor:
                 _fault_trip("executor.region", detail=f"segment{i}")
                 fn = self._jitted.get(i)
                 if fn is None:
-                    fn = self._jitted[i] = self._device_fn(payload)
-                with tile_scope(self._tile_config):
+                    fn = self._jitted[i] = self._device_fn(payload, i)
+                with tile_scope(self._tile_config), tracing.span(
+                        "ripple.dispatch", graph=self.graph.name,
+                        segment=i):
                     state = fn(state)
             elif kind == "loop":
                 fn = self._jitted.get(i)
                 if fn is None:
                     fn = self._jitted[i] = self._loop_fn(payload, i)
-                with tile_scope(self._tile_config):
+                with tile_scope(self._tile_config), tracing.span(
+                        "ripple.dispatch", graph=self.graph.name,
+                        segment=i):
                     state = fn(state)
             elif kind == "host_loop":
                 sub_exec = self._sub_executor(i)
                 # while semantics: check before the first iteration too
-                while bool(jax.device_get(payload.condition(state))):
+                while _read_condition(payload, state):
                     state = sub_exec(state)
             elif kind == "host":
                 node: Node = payload
-                jax.block_until_ready(jax.tree_util.tree_leaves(state))
+                with tracing.span("ripple.host_wait", reason="barrier"):
+                    jax.block_until_ready(jax.tree_util.tree_leaves(state))
                 _fault_trip("executor.host", detail=f"segment{i}")
                 if node.fn is not None:
                     vals = self._resolve_args(
                         node, state, False, self._state_layouts) \
                         if node.args else []
-                    node.fn(*vals)
+                    with tracing.span("ripple.host", segment=i):
+                        node.fn(*vals)
         return state
 
     def run(self, state: dict, steps: int) -> dict:
@@ -2208,9 +2284,20 @@ class Executor:
         # between jit calls every step, so it breaks the fori fusion).
         # regions=False escapes the fused/cached machinery entirely —
         # the escape hatch must not route through what it escapes.
-        if self.regions_enabled and self.graph.condition is None \
-                and self.dag.device_only:
-            return self._run_fused(state, steps)
+        fused = self.regions_enabled and self.graph.condition is None \
+            and self.dag.device_only
+        with tracing.span("ripple.run", new_run=True, graph=self.graph.name,
+                          steps=steps,
+                          path="fused" if fused else self._path()):
+            if fused:
+                state = self._run_fused(state, steps)
+            else:
+                state = self._run_passes(state, steps)
+            tracing.count("halo.bytes", self._halo_pass_bytes * steps)
+            return state
+
+    def _run_passes(self, state: dict, steps: int) -> dict:
+        """``steps`` passes over the region (or segment) schedule."""
         with self._layout_epoch():
             ctx = self._async_ctx()
             state = dict(state)
@@ -2221,7 +2308,8 @@ class Executor:
                 if ctx is not None:
                     # completion point of the epoch: every pooled host
                     # callback has run (or its failure re-raises here)
-                    ctx.drain()
+                    with tracing.span("ripple.host_wait", reason="drain"):
+                        ctx.drain()
                 self._note_clean_pass()
                 return state
             except BaseException as exc:
@@ -2253,27 +2341,31 @@ class Executor:
 
         def call(donated, kept, consts, steps):
             cache_entry.trace_events += 1
-            state = self._traced_convert({**donated, **kept}, convs,
-                                         body_layouts)
-
-            def loop(st, cs, n):
-                # read-only entries ride outside the loop carry
-                def body(_, s):
-                    out = self._lower_levels(levels, {**s, **cs}, sharded,
+            with tracing.span("ripple.trace", graph=self.graph.name,
+                              region="fused"):
+                state = self._traced_convert({**donated, **kept}, convs,
                                              body_layouts)
-                    return {k: out[k] for k in s}
-                return lax.fori_loop(0, n, body, st)
 
-            if sharded:
-                fn = jax.shard_map(
-                    loop, mesh=self.mesh,
-                    in_specs=(self._state_specs(state, body_layouts),
-                              self._state_specs(consts, body_layouts), P()),
-                    out_specs=self._state_specs(state, body_layouts),
-                    check_vma=False)
-                return fn(state, consts, steps)
-            return loop(state, consts, steps)
+                def loop(st, cs, n):
+                    # read-only entries ride outside the loop carry
+                    def body(_, s):
+                        out = self._lower_levels(levels, {**s, **cs},
+                                                 sharded, body_layouts)
+                        return {k: out[k] for k in s}
+                    return lax.fori_loop(0, n, body, st)
 
+                if sharded:
+                    fn = jax.shard_map(
+                        loop, mesh=self.mesh,
+                        in_specs=(self._state_specs(state, body_layouts),
+                                  self._state_specs(consts, body_layouts),
+                                  P()),
+                        out_specs=self._state_specs(state, body_layouts),
+                        check_vma=False)
+                    return fn(state, consts, steps)
+                return loop(state, consts, steps)
+
+        call.__name__ = f"{self._exe_name}_fused"
         jfn = jax.jit(call, donate_argnums=(0,) if self.donate else ())
         tile_config = self._tile_config
 
@@ -2298,7 +2390,9 @@ class Executor:
                 entry = dict(self._state_layouts)
                 key = ("fused", self._layout_sig(entry))
                 fn = self._fetch(key, lambda: self._build_fused_fn(entry))
-                out = fn(dict(state), steps)
+                with tracing.span("ripple.dispatch", graph=self.graph.name,
+                                  region="fused"):
+                    out = fn(dict(state), steps)
                 self._state_layouts.update(fn.exit_layouts)
                 out = self._restore_initial_layouts(dict(out))
             except BaseException as exc:
@@ -2306,6 +2400,13 @@ class Executor:
                 raise
             self._note_clean_pass()
             return out
+
+
+def _read_condition(graph: Graph, state: dict) -> bool:
+    """A host loop's condition, read back to the host (the dispatcher
+    waits for the device here)."""
+    with tracing.span("ripple.host_wait", reason="condition"):
+        return bool(jax.device_get(graph.condition(state)))
 
 
 def execute(graph: Graph, mesh: Optional[Mesh] = None, steps: int = 1,

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import enum
 import itertools
+from collections import Counter
 from dataclasses import dataclass, field as dfield
 from typing import Any, Callable, Optional, Sequence, Union
 
@@ -519,6 +520,34 @@ class Graph:
             if node.exec_kind is ExecutionKind.Cpu and node.kind != "subgraph":
                 return False
         return True
+
+    def node_scopes(self) -> dict[int, str]:
+        """``<graph name>/<label>`` for every node, keyed by ``id(node)``:
+        the name scope the executor lowers the node under, so a device
+        op's metadata names its node (``euler_step/update_x``).  The label
+        is the node function's ``__name__``, or ``reduce:<result>`` for a
+        reduction, with ``[<level>]`` added where labels tie (XLA reads
+        an ``@`` in a scope as a separator); a subgraph's nodes are scoped
+        under that subgraph's own name."""
+        labels = []
+        for i, level in enumerate(self.levels):
+            for n in level:
+                if n.subgraph is not None:
+                    continue
+                if n.kind == "reduce":
+                    base = f"reduce:{n.result.name}"
+                else:
+                    fn = getattr(n.fn, "func", n.fn)   # a partial's function
+                    base = getattr(fn, "__name__", None) or n.kind
+                labels.append((n, base, i))
+        ties = Counter(base for _, base, _ in labels)
+        out = {id(n): f"{self.name}/{base}"
+               + (f"[{i}]" if ties[base] > 1 else "")
+               for n, base, i in labels}
+        for n in self.nodes():
+            if n.subgraph is not None:
+                out.update(n.subgraph.node_scopes())
+        return out
 
     def summary(self) -> str:
         """One line per node: level, kind, and the tensors it touches."""

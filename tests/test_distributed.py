@@ -454,3 +454,30 @@ d = abs(float(m["loss"]) - float(m1["loss"]))
 assert d < 5e-3, d
 print("OK", d)
 """, timeout=1800)
+
+
+@pytest.mark.slow
+def test_sharded_program_does_not_depend_on_string_hashing(monkeypatch):
+    """A reduction over a 2-D mesh lowers to the same program whatever
+    ``PYTHONHASHSEED`` a process draws (a set's order once chose the
+    all-reduce's axis order, and with it the compile-cache key)."""
+    code = """
+import hashlib, re
+import jax.numpy as jnp
+from repro.core import (DistTensor, Executor, Graph, SumReducer,
+                        make_mesh, make_reduction_result)
+mesh = make_mesh((2, 2), ("gx", "gy"))
+u = DistTensor("u", (8, 8), partition=("gx", "gy"))
+total = make_reduction_result("total")
+g = Graph(name="sum2d")
+g.reduce(u, total, SumReducer())
+ex = Executor(g, mesh=mesh)
+hlo = re.sub(r"metadata=[{][^}]*[}]", "", ex.region_hlo(ex.init_state()))
+print("HLO", hashlib.sha1(hlo.encode()).hexdigest())
+"""
+    digests = set()
+    for seed in ("0", "1"):   # ("gx", "gy") and ("gy", "gx") as set orders
+        monkeypatch.setenv("PYTHONHASHSEED", seed)
+        out = run_subprocess_devices(code, n_devices=4)
+        digests.add(out.split("HLO ")[1].strip())
+    assert len(digests) == 1

@@ -206,3 +206,47 @@ def test_qwen_decode_layer_region_compiles_for_v5e(one_chip):
     assert max((_shape_bytes(op.result_sig)
                 for comp in model.comps.values() for op in comp.ops
                 if op.opcode == "constant"), default=0) < 1 << 20
+
+
+# -- the eikonal kernel's name, which the benchmark's roofline reads ----------
+
+# bench/metrics/eikonal_sweep_hbm_roofline.py matches the kernel's op by this
+EIKONAL_KERNEL = r"^%_eikonal_fim_jit[.\d]* = .*custom_call_target=\"tpu_custom_call\""
+
+
+def test_eikonal_sweep_region_keeps_its_kernel_name_for_v5e(one_chip,
+                                                            monkeypatch):
+    """The FIM sweep in a device loop, as the benchmark builds it: the
+    compiled region holds the Pallas call under the instruction name the
+    roofline reader matches, with the node's name scope in its
+    metadata."""
+    import re
+
+    from repro.core import (DistTensor, Executor, Graph, MaxReducer,
+                            make_reduction_result)
+    from repro.kernels.eikonal import kernel
+    from repro.kernels.eikonal.ops import make_eikonal_graph
+
+    monkeypatch.setattr(kernel, "interpret_mode",
+                        lambda interpret=None: False)
+    phi = DistTensor("phi", (GRID, GRID), halo=(1, 1))
+    mask = DistTensor("mask", (GRID, GRID), dtype=jnp.bool_)
+    res = make_reduction_result("res", init=1.0)
+    loop = make_eikonal_graph(phi, mask, 1.0 / GRID, overlap=False,
+                              use_pallas=True, graph=Graph(name="fim_sweep"))
+    loop.then_reduce(phi, res, MaxReducer())
+    loop.conditional(lambda s: s["res"] > 0)
+    solve = Graph(name="fim_solve")
+    solve.emplace(loop)
+    ex = Executor(solve)
+    state = {name: _sds(one_chip, ex._eff(t).storage_shape, t.dtype)
+             for name, t in ex.tensors.items()}
+    state["res"] = _sds(one_chip, (), res.dtype)
+    fn, _ = ex._region_executable(ex._regions[0])
+    text = fn.jit_fn.lower(
+        *ex._split_state(state, fn.donate_keys, fn.read_only)
+    ).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if re.match(EIKONAL_KERNEL, line.strip())]
+    assert calls, "no tpu_custom_call named _eikonal_fim_jit"
+    assert all("/fim_sweep/sweep/" in c for c in calls), calls

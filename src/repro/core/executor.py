@@ -1556,7 +1556,12 @@ class Executor:
                 v = eff.init(self.mesh)
                 state[name] = v.data if isinstance(v, RecordArray) else v
         for name, r in self.results.items():
-            state[name] = jnp.asarray(r.init, dtype=r.dtype)
+            v = jnp.asarray(r.init, dtype=r.dtype)
+            if self.mesh is not None:
+                # replicated, as every call returns it: the next call's
+                # inputs then match the first's, which traces once
+                v = jax.device_put(v, NamedSharding(self.mesh, P()))
+            state[name] = v
         return state
 
     def _infer_override_layout(self, t: DistTensor, shape) -> Layout:

@@ -1,5 +1,6 @@
-"""Pallas kernels for the paper's hot spots (SAXPY, particles, FORCE flux,
-eikonal FIM) and the LM twins (flash attention, SSD).
+"""Pallas kernels for the paper's hot spots (SAXPY, particles, FORCE flux
+and the split FORCE update, eikonal FIM) and the LM twins (flash
+attention, SSD).
 
 Every kernel runs compiled by Mosaic on a TPU and in the Pallas
 interpreter elsewhere; :func:`interpret_mode` is the one place that

@@ -13,6 +13,11 @@ Stencil over a haloed 2-D Euler state record:
   measures;
 * block shape = the paper's sub-partition knob (§4.1), hardware-aligned
   to multiples of (8, 128) for the VPU.
+
+Its body traces ``euler.flux_difference``, whose jnp formula stays jnp.
+The dimension-split update that the Euler solvers run,
+``euler.update_dim``, has its own kernel beside this one (``update.py``),
+which it calls on a TPU.
 """
 
 from __future__ import annotations

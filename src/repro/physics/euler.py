@@ -6,6 +6,11 @@ All functions operate on a *stacked* component-major array ``U`` of shape
 ``(4, *space)`` — which is exactly the SoA storage of the record, so the
 SoA path is zero-copy while AoS pays a transpose (the paper's layout
 effect, made structural).
+
+On a TPU, :func:`update_dim` runs as one Pallas pass
+(``repro.kernels.stencil.update``) whenever its float32 output fills an
+(8, 128) tile; everywhere else, and for the 1-wide boundary strips of an
+overlapped split, it is the jnp formula of this module.
 """
 
 from __future__ import annotations
@@ -80,9 +85,17 @@ def flux(U: jax.Array, dim: int) -> jax.Array:
 def force_flux(UL: jax.Array, UR: jax.Array, dim: int, lam) -> jax.Array:
     """FORCE flux (first-ORder CEntred, Toro): mean of Lax-Friedrichs and
     Richtmyer fluxes at the interface.  ``lam = dt / dx``."""
-    FL, FR = flux(UL, dim), flux(UR, dim)
-    f_lf = 0.5 * (FL + FR) - 0.5 / lam * (UR - UL)
-    u_rm = 0.5 * (UL + UR) - 0.5 * lam * (FR - FL)
+    return force_flux_of(UL, UR, flux(UL, dim), flux(UR, dim), dim,
+                         0.5 / lam, 0.5 * lam)
+
+
+def force_flux_of(UL, UR, FL, FR, dim: int, half_over_lam, half_lam):
+    """:func:`force_flux` from the physical fluxes ``FL``/``FR`` of the two
+    sides and the coefficients ``0.5 / lam`` and ``0.5 * lam``: the same
+    arithmetic, for a caller that holds the coefficients already (the
+    update kernel reads them from SMEM, computed once outside)."""
+    f_lf = 0.5 * (FL + FR) - half_over_lam * (UR - UL)
+    u_rm = 0.5 * (UL + UR) - half_lam * (FR - FL)
     return 0.5 * (f_lf + flux(u_rm, dim))
 
 
@@ -116,7 +129,27 @@ def flux_difference(U_haloed: jax.Array, lam_x, lam_y) -> jax.Array:
 
 def update_dim(U_haloed: jax.Array, dim: int, lam) -> jax.Array:
     """Dimension-split FORCE update (paper Listing 12: update_state_x/y):
-    U' = U - lam (F_{+} - F_{-}).  Haloed by 1 in ``dim`` only."""
+    U' = U - lam (F_{+} - F_{-}).  Haloed by 1 in ``dim`` only.
+
+    On a TPU, a float32 update whose output fills at least one (8, 128)
+    tile runs as one Pallas pass (``kernels/stencil/update.py``) that
+    keeps the face fluxes in VMEM; elsewhere, and for thin boundary
+    strips, the jnp formula below (the kernel's reference)."""
+    from repro import kernels
+
+    out = list(U_haloed.shape[1:])
+    out[dim] -= 2
+    if not kernels.interpret_mode():
+        from repro.kernels.stencil import update
+
+        if update.fits(tuple(out), U_haloed.dtype):
+            return update.force_update_pallas(U_haloed, dim, lam,
+                                              interpret=False)
+    return _update_dim(U_haloed, dim, lam)
+
+
+def _update_dim(U_haloed: jax.Array, dim: int, lam) -> jax.Array:
+    """:func:`update_dim` as a jnp formula."""
     n = U_haloed.shape[dim + 1] - 2
     return _shift(U_haloed, dim, 1, n) - flux_difference_dim(U_haloed, dim, lam)
 

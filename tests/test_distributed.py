@@ -481,3 +481,28 @@ print("HLO", hashlib.sha1(hlo.encode()).hexdigest())
         out = run_subprocess_devices(code, n_devices=4)
         digests.add(out.split("HLO ")[1].strip())
     assert len(digests) == 1
+
+
+def test_mesh_call_fed_its_own_output_traces_nothing_new():
+    """``init_state`` places a mesh's reduction results replicated, as
+    every call returns them, so a call fed the previous call's output
+    runs the program the first call traced."""
+    code = """
+import jax.numpy as jnp
+from repro.core import (DistTensor, Executor, Graph, SumReducer,
+                        make_mesh, make_reduction_result)
+mesh = make_mesh((2, 2), ("gx", "gy"))
+u = DistTensor("u", (8, 8), partition=("gx", "gy"))
+total = make_reduction_result("total")
+g = Graph(name="sum2d")
+g.reduce(u, total, SumReducer())
+ex = Executor(g, mesh=mesh)
+state = ex.run(ex.init_state(u=jnp.ones((8, 8))), steps=2)
+first = ex.cache_stats()["trace_events"]
+state = ex.run(state, steps=2)
+print("TRACES", first, ex.cache_stats()["trace_events"], float(state["total"]))
+"""
+    first, after, total = run_subprocess_devices(code, n_devices=4).split(
+        "TRACES ")[1].split()
+    assert int(first) >= 1 and int(after) == int(first)
+    assert float(total) == 64.0

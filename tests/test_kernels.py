@@ -105,6 +105,72 @@ def test_flux_sweep(shape, layout):
                                atol=1e-5)
 
 
+# -- split FORCE update (euler.update_dim's kernel) ----------------------------
+
+def _euler_state(shape, seed=0):
+    """A float32 Euler state with varied density, velocity and pressure."""
+    r = np.random.default_rng(seed)
+    rho = 1.0 + r.random(shape)
+    u, v = r.standard_normal(shape), r.standard_normal(shape)
+    p = 1.0 + r.random(shape)
+    E = p / 0.4 + 0.5 * rho * (u * u + v * v)
+    return jnp.asarray(np.stack([rho, E, rho * u, rho * v]), jnp.float32)
+
+
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("out,block,boundary", [
+    ((64, 256), (32, 128), Boundary.TRANSMISSIVE),    # tiles the interior
+    ((62, 250), (32, 128), Boundary.TRANSMISSIVE),    # ragged both ways
+    ((62, 250), (64, 1024), Boundary.PERIODIC),       # one ragged tile
+    ((30, 256), (8, 128), Boundary.PERIODIC),         # 2x2 x interior, small
+    ((32, 254), (16, 128), Boundary.TRANSMISSIVE),    # 2x2 y interior, small
+    ((8, 128), (8, 128), Boundary.TRANSMISSIVE),      # one vreg
+])
+def test_force_update_kernel_matches_update_dim(dim, out, block, boundary):
+    """The kernel in interpret mode against the jnp formula, with ``lam``
+    traced: float32 rounding (1e-6 of the field's scale) at most."""
+    from repro.kernels.stencil.update import force_update_pallas
+    from repro.physics import euler
+
+    U = pad_boundary_only(_euler_state(out), axis=dim + 1, width=1,
+                          boundary=boundary)
+    lam = jnp.float32(0.13)
+    got = jax.jit(lambda u, l: force_update_pallas(u, dim, l, block=block,
+                                                   interpret=True))(U, lam)
+    want = jax.jit(lambda u, l: euler._update_dim(u, dim, l))(U, lam)
+    assert got.shape == want.shape == (4, *out)
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) <= 1e-6 * scale
+
+
+@pytest.mark.parametrize("dim,space,dtype,kernel", [
+    (0, (3, 256), jnp.float32, False),     # a 1-wide boundary strip
+    (1, (64, 3), jnp.float32, False),
+    (1, (64, 129), jnp.float32, False),    # 127 lanes out: under one tile
+    (0, (9, 128), jnp.bfloat16, False),    # the kernel is float32 only
+    (0, (10, 128), jnp.float32, True),     # one (8, 128) tile out
+    (1, (8, 130), jnp.float32, True),
+])
+def test_update_dim_takes_the_kernel_on_a_tpu_when_a_tile_fits(
+        monkeypatch, dim, space, dtype, kernel):
+    """On a TPU ``update_dim`` calls the kernel when the output fills an
+    (8, 128) tile and keeps the jnp formula for thin strips."""
+    from repro.kernels.stencil import update
+    from repro.physics import euler
+
+    calls = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(update, "force_update_pallas",
+                        lambda u, d, lam, **kw: calls.append(kw) or u)
+    U = _euler_state(space).astype(dtype)
+    out = euler.update_dim(U, dim, 0.1)
+    assert calls == ([{"interpret": False}] if kernel else [])
+    if not kernel:
+        want = list(space)
+        want[dim] -= 2
+        assert out.shape == (4, *want)
+
+
 # -- eikonal (FIM) --------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [32, 64])

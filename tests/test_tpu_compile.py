@@ -131,12 +131,26 @@ def _ssd(s):
             _sds(s, (B, S, N)), _sds(s, (B, S, N)))
 
 
+def _force_update(s, block=None):
+    """Both split FORCE updates at GRID^2, as the Pallas kernel."""
+    from repro.kernels.stencil.update import force_update_pallas
+
+    def both(ux, uy, lam):
+        return tuple(force_update_pallas(u, dim, lam, block=block,
+                                         interpret=False)
+                     for dim, u in enumerate((ux, uy)))
+
+    return (both, _sds(s, (4, GRID + 2, GRID)), _sds(s, (4, GRID, GRID + 2)),
+            _sds(s, ()))
+
+
 KERNELS = {
     "saxpy": _saxpy,
     "saxpy_record_soa": _saxpy_record,
     "particle_aosoa": _particle,
     "flux_soa": _flux,
     "eikonal": _eikonal,
+    "force_update_soa": _force_update,
     "attention_soa": _attention,
     "attention_fused_kv": lambda s: _attention(s, fused=True),
     "ssd_intra_chunk": _ssd,
@@ -155,8 +169,11 @@ def _tile_cases():
     from repro.kernels.attention import kernel as attention
     from repro.kernels.eikonal import kernel as eikonal
     from repro.kernels.stencil import kernel as stencil
+    from repro.kernels.stencil import update
 
     cases = [("flux", b) for b in stencil.tile_candidates((GRID, GRID))]
+    cases += [("force_update", b)
+              for b in update.tile_candidates((GRID, GRID))]
     cases += [("eikonal", b) for b in eikonal.tile_candidates((GRID, GRID))]
     cases += [("attention", b)
               for b in attention.tile_candidates((1024, 1024))]
@@ -167,7 +184,7 @@ def _tile_cases():
                          ids=lambda v: "x".join(map(str, v))
                          if isinstance(v, tuple) else v)
 def test_tile_candidate_compiles_for_v5e(one_chip, kernel, tile):
-    build = {"flux": _flux, "eikonal": _eikonal,
+    build = {"flux": _flux, "eikonal": _eikonal, "force_update": _force_update,
              "attention": _attention}[kernel]
     fn, *args = build(one_chip, tile)
     assert "tpu_custom_call" in _compiled_text(fn, *args)
@@ -250,3 +267,40 @@ def test_eikonal_sweep_region_keeps_its_kernel_name_for_v5e(one_chip,
              if re.match(EIKONAL_KERNEL, line.strip())]
     assert calls, "no tpu_custom_call named _eikonal_fim_jit"
     assert all("/fim_sweep/sweep/" in c for c in calls), calls
+
+
+# -- the split FORCE update's kernel, which its roofline reads -----------------
+
+# bench/metrics/euler_update_hbm_roofline.py matches the kernel's op by this
+FORCE_UPDATE_KERNEL = (r"^(ROOT )?%euler_force_update[.\d]* = "
+                       r".*custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.mark.parametrize("dim,space", [
+    (0, (16386, 8192)),    # euler.chunk10, the x update
+    (1, (16384, 8194)),    # euler.chunk10, the y update
+    (0, (8192, 4096)),     # euler2x2.chunk10, the x interior (8190 out)
+    (1, (8192, 4096)),     # euler2x2.chunk10, the y interior (4094 out)
+], ids=["x", "y", "x_2x2_interior", "y_2x2_interior"])
+def test_update_dim_runs_one_named_kernel_for_v5e(one_chip, monkeypatch,
+                                                  dim, space):
+    """``euler.update_dim`` on a TPU at the benchmark cells' shapes: one
+    Pallas call under the name the roofline reader matches, and no
+    full-grid face-flux temporaries (the XLA update's
+    ``f32[1,16385,8192]`` fusion outputs)."""
+    import re
+
+    import repro.kernels
+    from repro.physics import euler
+
+    monkeypatch.setattr(repro.kernels, "interpret_mode",
+                        lambda interpret=None: False)
+    text = _compiled_text(lambda u, lam: euler.update_dim(u, dim, lam),
+                          _sds(one_chip, (4, *space)), _sds(one_chip, ()))
+    lines = [line.strip() for line in text.splitlines()]
+    calls = [line for line in lines if re.match(FORCE_UPDATE_KERNEL, line)]
+    assert len(calls) == 1, [l for l in lines if "custom_call" in l]
+    faces = list(space)             # n + 1 faces along the update
+    faces[dim] -= 1
+    assert not [line for line in lines
+                if "{},{}]".format(*faces) in line]

@@ -63,6 +63,14 @@ decisions* (DESIGN.md §2/§4), which this executor makes explicitly:
   ``core/schedule.py``'s ``region_dag``/``region_waves`` give regions —
   not just nodes — explicit dependencies, rendered by
   ``plan.describe()`` as ready waves;
+* a host node with a period (``every=k`` where it is added) runs on the
+  passes ``i`` of a ``run()`` with ``i % k == 0``.  Where every host node
+  is such a node and reads only what its pass starts with (the
+  scheduler's ``ScheduleDag.periodic_hosts``), ``run()`` takes the
+  ``fused+host`` path: the passes between due passes run as chunks of
+  the fused fori_loop executable (one trace for every chunk length), and
+  each due node gets the state between two chunks, through the pool
+  with one double-buffer copy (``ripple_host_snapshot``) under donation;
 * a graph with ``conditional`` becomes a ``lax.while_loop`` (device) or a
   host do/while (if it contains host nodes); device loops trace straight
   into their enclosing region, host loops run a cached sub-``Executor``;
@@ -110,6 +118,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from .. import kernels as kernels_lib
 from .. import tracing
 from ..runtime.faults import (HostTimeoutError, TransientError,
                               trip as _fault_trip)
@@ -217,17 +226,50 @@ def _host_pool() -> ThreadPoolExecutor:
         return _HOST_POOL
 
 
-def _snapshot_for_host(v):
-    """Device-side copy of one resolved host argument — the double
-    buffer under donation: the callback reads the snapshot while the
-    next region call donates (and XLA overwrites) the original buffer.
-    The copy itself is async-dispatched, so it rides the device stream
-    *before* the overwrite without blocking the dispatcher."""
-    if isinstance(v, RecordArray):
-        return RecordArray(jnp.copy(v.data), v.spec, v.layout)
-    if isinstance(v, jax.Array):
-        return jnp.copy(v)
-    return v
+@partial(jax.jit, static_argnums=(1,))
+def ripple_host_snapshot(arrays, kernels):
+    """The double-buffer copies of one host node's arguments, as one
+    executable; ``kernels[i]`` picks the ``host_snapshot`` DMA kernel
+    for ``arrays[i]``."""
+    # loaded here, like ``update_dim``'s kernel: only graphs with host
+    # nodes under donation pay for the Pallas import
+    from ..kernels.snapshot import snapshot_copy
+
+    with jax.named_scope("host_snapshot"):
+        return [snapshot_copy(a, k) for a, k in zip(arrays, kernels)]
+
+
+def _chip_bytes(a: jax.Array) -> int:
+    """Bytes of ``a`` that one chip holds (its shard)."""
+    return math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+
+
+def _snapshot_for_host(vals) -> list:
+    """Device-side copies of a host node's resolved arguments — the
+    double buffer under donation: the callback reads the snapshot while
+    the next executable call donates (and XLA overwrites) the original
+    buffers.  One call of ``ripple_host_snapshot`` copies every array
+    among them, async-dispatched, so it rides the device stream *before*
+    the overwrite without blocking the dispatcher.  On a TPU an array of
+    rank >= 2 on one device goes through the ``host_snapshot`` kernel,
+    the name the device trace shows.  ``host.copy_bytes`` counts the
+    bytes one chip copies."""
+    arrays = _host_arg_leaves(vals)
+    if not arrays:
+        return list(vals)
+    tpu = not kernels_lib.interpret_mode()
+    kernels = tuple(tpu and a.ndim >= 2 and len(a.sharding.device_set) == 1
+                    for a in arrays)
+    copies = iter(ripple_host_snapshot(arrays, kernels))
+    tracing.count("host.copy_bytes", sum(map(_chip_bytes, arrays)))
+    out = []
+    for v in vals:
+        if isinstance(v, RecordArray):
+            v = RecordArray(next(copies), v.spec, v.layout)
+        elif isinstance(v, jax.Array):
+            v = next(copies)
+        out.append(v)
+    return out
 
 
 def _host_arg_leaves(vals) -> list:
@@ -274,19 +316,25 @@ class _AsyncRun:
         self._prev = None        # tail of the host-order chain
         self._cancelled = threading.Event()
 
-    def submit(self, region_index: int, fn, vals) -> None:
+    def submit(self, region_index: int, fn, vals,
+               label: Optional[str] = None) -> None:
+        """Queue ``fn(*vals)`` behind the previous host task; ``label``
+        names the node on its ``ripple.host`` span."""
         self.check()
         _fault_trip("executor.dispatch", detail=f"region{region_index}")
         if len(self.tasks) >= self.max_inflight:
             self._wait_oldest()
         if self.donate:
-            vals = [_snapshot_for_host(v) for v in vals]
-        leaves = _host_arg_leaves(vals)
+            vals = _snapshot_for_host(vals)
+        # the task lets go of its arguments (the snapshot) when it
+        # returns, before its future completes
+        held = [(vals, _host_arg_leaves(vals))]
         prev = self._prev
         timeout = self.host_timeout
         cancelled = self._cancelled
 
         def task():
+            vals, leaves = held.pop()
             if cancelled.is_set():
                 raise _HostTaskCancelled()
             # Future.exception() blocks until prev completes — this IS
@@ -304,7 +352,8 @@ class _AsyncRun:
             jax.block_until_ready(leaves)
             _fault_trip("executor.host", detail=f"region{region_index}")
             if fn is not None:
-                with tracing.span("ripple.host", region=region_index):
+                with tracing.span("ripple.host", region=region_index,
+                                  node=label):
                     fn(*vals)
 
         fut = _host_pool().submit(tracing.carry(task))
@@ -324,10 +373,10 @@ class _AsyncRun:
             err.site = "executor.host"
             raise err from None
 
-    def _wait_oldest(self) -> None:
+    def _wait_oldest(self, reason: str = "inflight") -> None:
         region_index, fut = self.tasks[0]
         try:
-            with tracing.span("ripple.host_wait", reason="inflight"):
+            with tracing.span("ripple.host_wait", reason=reason):
                 self._timed_result(region_index, fut)
         except _HostTaskCancelled:
             pass
@@ -1397,6 +1446,19 @@ class Executor:
                                                          self._regions)
         self._region_access = schedule_lib.region_access(self.dag,
                                                          self._regions)
+        # run()'s fused path: None where the graph cannot take it, else
+        # its leading periodic host nodes as (region index, node), [] for
+        # a device-only graph; with host nodes only where no pass
+        # converts a layout, so the fused chunks between them chain
+        hosts = self.dag.periodic_hosts()
+        if hosts and any(lay is not self.plan.initial.get(n, lay)
+                         for seg in self.plan.per_segment
+                         for n, lay in seg.items()):
+            hosts = None
+        seg_region = {si: r.index for r in self._regions
+                      for si in r.segments}
+        self._periodic_hosts = None if hosts is None else [
+            (seg_region[u.segment], u.node) for u in hosts]
         self._plan_sig = plan_signature(self)
         self.plan.signature = hashlib.sha1(
             repr(self._plan_sig).encode()).hexdigest()[:12]
@@ -2161,14 +2223,17 @@ class Executor:
                 self.record_failure(exc)
                 raise
 
-    def _pass_once(self, state: dict,
-                   ctx: Optional[_AsyncRun] = None) -> dict:
+    def _pass_once(self, state: dict, ctx: Optional[_AsyncRun] = None,
+                   index: int = 0) -> dict:
+        """Pass ``index`` of a call over the region (or segment)
+        schedule; a periodic host node runs only where it is due."""
         if self.regions_enabled:
-            return self._run_regions_once(state, ctx)
-        return self._call_segments(state)
+            return self._run_regions_once(state, ctx, index)
+        return self._call_segments(state, index)
 
     def _run_regions_once(self, state: dict,
-                          ctx: Optional[_AsyncRun] = None) -> dict:
+                          ctx: Optional[_AsyncRun] = None,
+                          index: int = 0) -> dict:
         """One pass over the region schedule: each device region is ONE
         cached executable call (its relayouts and halo glue run inside
         the trace); host work runs eagerly between regions.  Layout
@@ -2198,27 +2263,11 @@ class Executor:
                 self._state_layouts.update(exit_layouts)
             elif region.kind == "host":
                 si = region.start
-                state = self._apply_segment_layouts(dict(state), si)
                 node: Node = self._segments[si][1]
-                barrier = self._region_access[region.index][2]
-                if ctx is not None and not barrier:
-                    vals = self._resolve_args(
-                        node, state, False, self._state_layouts) \
-                        if node.args else []
-                    ctx.submit(region.index, node.fn, vals)
+                if node.every is not None and index % node.every:
                     continue
-                with tracing.span("ripple.host_wait", reason="barrier"):
-                    if ctx is not None:
-                        ctx.drain()   # barrier: side-effect order vs pool
-                    jax.block_until_ready(jax.tree_util.tree_leaves(state))
-                _fault_trip("executor.host",
-                            detail=f"region{region.index}")
-                if node.fn is not None:
-                    vals = self._resolve_args(
-                        node, state, False, self._state_layouts) \
-                        if node.args else []
-                    with tracing.span("ripple.host", region=region.index):
-                        node.fn(*vals)
+                state = self._apply_segment_layouts(dict(state), si)
+                self._host_node(region.index, node, state, ctx)
             else:  # host_loop
                 si = region.start
                 state = self._apply_segment_layouts(dict(state), si)
@@ -2233,12 +2282,40 @@ class Executor:
                     state = sub(state)
         return state
 
-    def _call_segments(self, state: dict) -> dict:
+    def _host_node(self, region_index: int, node: Node, state: dict,
+                   ctx: Optional[_AsyncRun]) -> None:
+        """Run one host region's node on ``state``: submitted to the
+        pool (``ctx``, unless the region is a barrier), or inline once
+        the device has finished what ``state`` holds."""
+        label = self._scopes.get(id(node), node.name)
+        if ctx is not None and not self._region_access[region_index][2]:
+            vals = self._resolve_args(
+                node, state, False, self._state_layouts) \
+                if node.args else []
+            ctx.submit(region_index, node.fn, vals, label)
+            return
+        with tracing.span("ripple.host_wait", reason="barrier"):
+            if ctx is not None:
+                ctx.drain()   # barrier: side-effect order vs pool
+            jax.block_until_ready(jax.tree_util.tree_leaves(state))
+        _fault_trip("executor.host", detail=f"region{region_index}")
+        if node.fn is not None:
+            vals = self._resolve_args(
+                node, state, False, self._state_layouts) \
+                if node.args else []
+            with tracing.span("ripple.host", region=region_index,
+                              node=label):
+                node.fn(*vals)
+
+    def _call_segments(self, state: dict, index: int = 0) -> dict:
         """Per-segment dispatch (``regions=False``): one jit call per
         segment with eager relayout glue between them; relayouts are
         runtime-driven from the current physical layouts, so repeated
         passes only convert where consecutive iterations disagree."""
         for i, (kind, payload) in enumerate(self._segments):
+            if kind == "host" and payload.every is not None \
+                    and index % payload.every:
+                continue
             state = self._apply_segment_layouts(state, i)
             if kind == "device":
                 _fault_trip("executor.region", detail=f"segment{i}")
@@ -2271,7 +2348,9 @@ class Executor:
                     vals = self._resolve_args(
                         node, state, False, self._state_layouts) \
                         if node.args else []
-                    with tracing.span("ripple.host", segment=i):
+                    with tracing.span("ripple.host", segment=i,
+                                      node=self._scopes.get(id(node),
+                                                            node.name)):
                         node.fn(*vals)
         return state
 
@@ -2279,21 +2358,27 @@ class Executor:
         """Execute the whole graph ``steps`` times (graphs are built once,
         executed many — paper §5.3).  Device-only graphs without a
         condition run as one fori_loop with ``steps`` a DYNAMIC argument
-        (distinct step counts share a single trace); everything else
-        loops over the cached region executables."""
+        (distinct step counts share a single trace).  Graphs whose host
+        nodes are all leading periodic ones (``every=k``) run the same
+        fori_loop in chunks between the passes where a node is due
+        (``fused+host``).  Everything else loops over the cached region
+        executables, pass by pass."""
         if steps <= 0:
             return state
-        # the scheduler owns the fusability decision: only a DAG with no
-        # host / sync / loop vertex lowers every segment to device code,
-        # whatever the schedule mode (a host node anywhere must run
-        # between jit calls every step, so it breaks the fori fusion).
-        # regions=False escapes the fused/cached machinery entirely —
-        # the escape hatch must not route through what it escapes.
+        # the scheduler owns the fusability decision: only a DAG whose
+        # vertices are device nodes and leading periodic host nodes
+        # lowers its passes to device code, whatever the schedule mode
+        # (any other host node must run between jit calls every step,
+        # so it breaks the fori fusion).  regions=False escapes the
+        # fused/cached machinery entirely — the escape hatch must not
+        # route through what it escapes.
+        hosts = self._periodic_hosts
         fused = self.regions_enabled and self.graph.condition is None \
-            and self.dag.device_only
+            and hosts is not None
+        path = ("fused+host" if hosts else "fused") if fused \
+            else self._path()
         with tracing.span("ripple.run", new_run=True, graph=self.graph.name,
-                          steps=steps,
-                          path="fused" if fused else self._path()):
+                          steps=steps, path=path):
             if fused:
                 state = self._run_fused(state, steps)
             else:
@@ -2307,8 +2392,8 @@ class Executor:
             ctx = self._async_ctx()
             state = dict(state)
             try:
-                for _ in range(steps):
-                    state = self._pass_once(dict(state), ctx)
+                for i in range(steps):
+                    state = self._pass_once(dict(state), ctx, i)
                 state = self._restore_initial_layouts(dict(state))
                 if ctx is not None:
                     # completion point of the epoch: every pooled host
@@ -2324,20 +2409,26 @@ class Executor:
                 raise
 
     def _build_fused_fn(self, entry_layouts: dict[str, Layout]) -> Callable:
-        """Device-only fast path executable: entry relayouts traced up
-        front, then all segments' levels inside one fori_loop whose trip
+        """Fused path executable: entry relayouts traced up front, then
+        the device segments' levels inside one fori_loop whose trip
         count is a runtime argument — NOT closed over, so ``run(s, 3)``
         and ``run(s, 1000)`` share one trace.  (Device-only graphs have a
-        single segment, so layouts are loop-invariant by construction.)"""
+        single segment, so layouts are loop-invariant by construction; a
+        graph with periodic host nodes takes this path only where no
+        segment converts a layout, and its host segments run between
+        calls.)"""
         current = dict(entry_layouts)
         convs = []
-        for si in range(len(self._segments)):
+        for si, (kind, _) in enumerate(self._segments):
+            if kind != "device":
+                continue
             for n, lay in sorted(self.plan.per_segment[si].items()):
                 if current[n] is not lay:
                     convs.append((n, current[n], lay))
                     current[n] = lay
         body_layouts = dict(current)
-        levels = [lv for _, seg in self._segments for lv in seg]
+        levels = [lv for kind, seg in self._segments if kind == "device"
+                  for lv in seg]
         donate_keys, read_only = self._state_split(
             schedule_lib.graph_access(self.graph)[1], entry_layouts,
             body_layouts)
@@ -2387,24 +2478,60 @@ class Executor:
         return invoke
 
     def _run_fused(self, state: dict, steps: int) -> dict:
-        """Device-only fast path: all steps in one jitted fori_loop,
-        cached by plan signature + entry layouts."""
+        """The fused path: the steps in jitted fori_loops of one
+        executable, cached by plan signature + entry layouts.  A
+        device-only graph makes one call.  With leading periodic host
+        nodes, each pass where one is due starts a chunk: the due nodes
+        get the state as it stands between chunks (submitted to the pool
+        with one double-buffer copy under donation, or run inline), then
+        one call runs the chunk's steps.  Before a due pass's copies, the
+        dispatcher waits for the callbacks of the due passes before the
+        previous one (the ``slot`` wait), so the snapshots in device
+        memory are those of two due passes at most, however long the
+        call, and a callback has a whole chunk's time beyond its own
+        before it holds the device back.  The last callbacks drain at the
+        end, as on the pass-by-pass path, once the device has run the
+        last chunk: what the drain's ``ripple.host_wait`` then holds is
+        host work that the device's steps did not hide."""
+        hosts = self._periodic_hosts
+        due = schedule_lib.due_passes([n.every for _, n in hosts], steps)
+        if not due or due[0][0]:
+            due.insert(0, (0, []))
         with self._layout_epoch():
+            ctx = self._async_ctx()
             try:
                 _fault_trip("executor.region", detail="fused")
                 entry = dict(self._state_layouts)
                 key = ("fused", self._layout_sig(entry))
                 fn = self._fetch(key, lambda: self._build_fused_fn(entry))
-                with tracing.span("ripple.dispatch", graph=self.graph.name,
-                                  region="fused"):
-                    out = fn(dict(state), steps)
+                ends = [i for i, _ in due[1:]] + [steps]
+                last = 0    # pooled tasks of the previous due pass
+                for (start, js), end in zip(due, ends):
+                    if js and ctx is not None:
+                        # two due passes' copies alive at most: the
+                        # callbacks before the previous due pass had a
+                        # chunk's time to end
+                        while len(ctx.tasks) > last:
+                            ctx._wait_oldest("slot")
+                        last = len(js)
+                    for j in js:
+                        self._host_node(*hosts[j], state, ctx)
+                    with tracing.span("ripple.dispatch",
+                                      graph=self.graph.name, region="fused"):
+                        state = fn(dict(state), end - start)
                 self._state_layouts.update(fn.exit_layouts)
-                out = self._restore_initial_layouts(dict(out))
+                state = self._restore_initial_layouts(dict(state))
+                if ctx is not None and ctx.tasks:
+                    jax.block_until_ready(state)
+                    with tracing.span("ripple.host_wait", reason="drain"):
+                        ctx.drain()
             except BaseException as exc:
+                if ctx is not None:
+                    ctx.abort()
                 self.record_failure(exc)
                 raise
             self._note_clean_pass()
-            return out
+            return state
 
 
 def _read_condition(graph: Graph, state: dict) -> bool:

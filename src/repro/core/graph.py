@@ -313,6 +313,7 @@ class Node:
     result: Optional[ReductionResult] = None
     overlap: bool = False          # interior/boundary comm-compute overlap
     subgraph: Optional["Graph"] = None
+    every: Optional[int] = None    # host node's period in passes (None: each)
     name: str = dfield(default_factory=lambda: f"node{next(_node_counter)}")
 
     def tensor_args(self):
@@ -372,45 +373,77 @@ class Graph:
             out.append(a)
         return tuple(out)
 
-    def _add(self, level: list[Node], item, exec_kind, **kw) -> None:
+    def _period(self, every: Optional[int],
+                exec_kind: Optional[ExecutionKind]) -> Optional[int]:
+        """Check a node's ``every=``: a whole number of passes, >= 1, on a
+        host (Cpu) node only."""
+        if every is None:
+            return None
+        if self._exec(exec_kind) is not ExecutionKind.Cpu:
+            raise ValueError("every= is a host node's period: give it "
+                             "with exec_kind=ExecutionKind.Cpu")
+        if isinstance(every, bool) or not isinstance(every, int) \
+                or every < 1:
+            raise ValueError(f"every must be a whole number of passes "
+                             f">= 1, got {every!r}")
+        return every
+
+    def _add(self, level: list[Node], item, exec_kind, every=None,
+             **kw) -> None:
         if isinstance(item, Graph):
+            if every is not None:
+                raise ValueError("every= applies to host nodes, not to "
+                                 "subgraphs")
             level.append(Node(kind="loop" if item.condition else "subgraph",
                               subgraph=item,
                               exec_kind=self._exec(exec_kind)))
         else:
-            level.append(Node(fn=item, exec_kind=self._exec(exec_kind), **kw))
+            level.append(Node(fn=item, exec_kind=self._exec(exec_kind),
+                              every=self._period(every, exec_kind), **kw))
 
     # -- paper API -----------------------------------------------------------
     def emplace(self, *items, exec_kind: Optional[ExecutionKind] = None,
-                layout: Optional[Layout] = None, **kw) -> "Graph":
+                layout: Optional[Layout] = None, every: Optional[int] = None,
+                **kw) -> "Graph":
         """Add node(s)/subgraph(s) to the *current* level (parallel).
 
         ``layout=`` marks every record tensor in ``args`` with the node's
-        preferred layout (a solver hint, see ``core/executor.py``)."""
+        preferred layout (a solver hint, see ``core/executor.py``).
+        ``every=k`` gives a host node a period: it runs on pass ``i`` of
+        a ``run()`` where ``i % k == 0`` (passes 0, k, 2k, ...) and
+        nowhere else.  Example, a snapshot every 100 steps::
+
+            g.emplace(save, exec_kind=ExecutionKind.Cpu, args=(u,),
+                      every=100)
+        """
         if "args" in kw:
             kw["args"] = self._hint_args(tuple(kw["args"]), layout)
         level = self._current_level()
         for item in items:
-            self._add(level, item, exec_kind, kind="op", **kw)
+            self._add(level, item, exec_kind, every, kind="op", **kw)
         return self
 
     def then(self, *items, exec_kind: Optional[ExecutionKind] = None,
-             layout: Optional[Layout] = None, **kw) -> "Graph":
-        """Add node(s)/subgraph(s) on a *new* level (sequential dep)."""
+             layout: Optional[Layout] = None, every: Optional[int] = None,
+             **kw) -> "Graph":
+        """Add node(s)/subgraph(s) on a *new* level (sequential dep);
+        ``every=`` as in :meth:`emplace`."""
         if "args" in kw:
             kw["args"] = self._hint_args(tuple(kw["args"]), layout)
         level = self._new_level()
         for item in items:
-            self._add(level, item, exec_kind, kind="op", **kw)
+            self._add(level, item, exec_kind, every, kind="op", **kw)
         return self
 
     def split(self, fn: Callable, *args: NodeArg,
               writes: Optional[Sequence[int]] = None,
               exec_kind: Optional[ExecutionKind] = None,
               overlap: bool = False,
-              layout: Optional[Layout] = None) -> "Graph":
+              layout: Optional[Layout] = None,
+              every: Optional[int] = None) -> "Graph":
         """Tensor op on the current level; becomes one node per partition
         (paper §5.3.3) — here: SPMD over the tensor's mesh axes.
+        ``every=`` gives a host split its period, as in :meth:`emplace`.
 
         ``overlap=True`` asks for the interior/boundary lowering: the
         padded args' halo transfers (all partitioned axes, corners
@@ -422,19 +455,21 @@ class Graph:
         self._current_level().append(
             Node(kind="split", fn=fn, args=self._hint_args(args, layout),
                  writes=None if writes is None else tuple(writes),
-                 exec_kind=self._exec(exec_kind), overlap=overlap))
+                 exec_kind=self._exec(exec_kind), overlap=overlap,
+                 every=self._period(every, exec_kind)))
         return self
 
     def then_split(self, fn: Callable, *args: NodeArg,
                    writes: Optional[Sequence[int]] = None,
                    exec_kind: Optional[ExecutionKind] = None,
                    overlap: bool = False,
-                   layout: Optional[Layout] = None) -> "Graph":
+                   layout: Optional[Layout] = None,
+                   every: Optional[int] = None) -> "Graph":
         """:meth:`split` on a *new* level (sequential dependency on the
         current one)."""
         self._new_level()
         return self.split(fn, *args, writes=writes, exec_kind=exec_kind,
-                          overlap=overlap, layout=layout)
+                          overlap=overlap, layout=layout, every=every)
 
     def reduce(self, tensor: DistTensor, result: ReductionResult,
                reducer: Reducer, field: Optional[str] = None) -> "Graph":

@@ -66,6 +66,7 @@ __all__ = [
     "region_waves",
     "sequential_segments",
     "place_units",
+    "due_passes",
 ]
 
 # Sentinel state key: the unit may read ANY state entry (opaque predicate
@@ -221,8 +222,33 @@ class ScheduleDag:
     def device_only(self) -> bool:
         """True iff every vertex is a device node — the whole graph can
         be fused into one jitted program (and ``Executor.run`` may wrap
-        all steps in a single fori_loop)."""
-        return all(u.kind == "device" for u in self.units)
+        all steps in a single fori_loop): ``periodic_hosts()`` is
+        ``[]``."""
+        return self.periodic_hosts() == []
+
+    def periodic_hosts(self) -> Optional[list[DagUnit]]:
+        """The host units, in program order, when every vertex that is
+        not a device node is a *leading periodic* host node; ``None``
+        when some vertex is anything else.  A device-only graph gives
+        ``[]``.
+
+        Leading periodic: the node has a period (``every=k``), has tensor
+        arguments (so it is no barrier), and reads nothing that an
+        earlier node writes (no RAW edge ends at it).  It then reads, on
+        any pass, the state the pass started with, and skipping it
+        changes no device value, so ``Executor.run`` fuses the passes
+        between the ones where such nodes are due, and hands each due
+        node the state between two fused chunks."""
+        raw_dst = {e.dst for e in self.edges if e.reason == "raw"}
+        hosts = []
+        for u in self.units:
+            if u.kind == "device":
+                continue
+            if u.kind != "host" or u.node.every is None or u.barrier \
+                    or u.uid in raw_dst:
+                return None
+            hosts.append(u)
+        return hosts
 
     def antichains(self) -> list[list[DagUnit]]:
         """The scheduled waves (unit groups that share a segment+wave),
@@ -406,6 +432,17 @@ def build_dag(graph: Graph) -> ScheduleDag:
             edges.append(DagEdge(a.uid, b.uid, "host-order"))
     edges.sort(key=lambda e: (e.src, e.dst))
     return ScheduleDag(graph, units, edges)
+
+
+def due_passes(periods, steps: int) -> list[tuple[int, list[int]]]:
+    """The passes of a ``steps``-pass run at which periodic nodes are
+    due, in order: ``(pass, [indices into periods])``, a node of period
+    ``k`` being due on every pass ``i`` with ``i % k == 0``."""
+    due: dict[int, list[int]] = defaultdict(list)
+    for j, k in enumerate(periods):
+        for i in range(0, steps, k):
+            due[i].append(j)
+    return sorted(due.items())
 
 
 def dag_segments(dag: ScheduleDag) -> list[tuple]:

@@ -304,3 +304,61 @@ def test_update_dim_runs_one_named_kernel_for_v5e(one_chip, monkeypatch,
     faces[dim] -= 1
     assert not [line for line in lines
                 if "{},{}]".format(*faces) in line]
+
+
+# -- the snapshot cell: its fused chunk and its named copy ----------------------
+
+# bench/metrics/snapshot_copy_hbm_roofline.py matches the copy's op by this
+SNAPSHOT_COPY = (r"^(ROOT )?%host_snapshot[.\d]* = "
+                 r".*custom_call_target=\"tpu_custom_call\"")
+
+
+def test_snapshot_graph_fuses_its_step_and_names_its_copy_for_v5e(
+        one_chip, monkeypatch):
+    """The ``euler.snapshot100`` graph at 16384 x 8192: ``run()`` takes
+    the fused path with its snapshot node, the chunk's one program holds
+    both ``euler_force_update`` calls, and the double-buffer copy of the
+    state compiles to the ``host_snapshot`` kernel the roofline reader
+    matches."""
+    import importlib.util
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    import repro.kernels
+    from repro.core.executor import ripple_host_snapshot
+
+    root = Path(__file__).resolve().parents[1]
+    monkeypatch.setattr(repro.kernels, "interpret_mode",
+                        lambda interpret=None: False)
+    monkeypatch.syspath_prepend(str(root / "bench"))
+    spec = importlib.util.spec_from_file_location(
+        "bench_model_euler_snapshot",
+        root / "bench" / "models" / "euler_snapshot.py")
+    model = importlib.util.module_from_spec(spec)
+    monkeypatch.setitem(sys.modules, spec.name, model)
+    spec.loader.exec_module(model)
+    cfg = json.loads((root / "bench" / "configs"
+                      / "euler-sb-16384x8192-snap100.json").read_text())
+
+    ex = model.build_solver(cfg, model.HostRing(cfg))
+    assert [n.every for _, n in ex._periodic_hosts] == [100]
+    state = {name: _sds(one_chip, ex._eff(t).storage_shape, t.dtype)
+             for name, t in ex.tensors.items()}
+    for name, r in ex.results.items():
+        state[name] = _sds(one_chip, (), r.dtype)
+    fn = ex._build_fused_fn(dict(ex.plan.initial))
+    text = fn.jit_fn.lower(
+        *ex._split_state(state, fn.donate_keys, fn.read_only),
+        _sds(one_chip, (), jnp.int32)).compile().as_text()
+    lines = [line.strip() for line in text.splitlines()]
+    updates = [line for line in lines if re.match(FORCE_UPDATE_KERNEL, line)]
+    assert len(updates) == 2, [l for l in lines if "custom_call" in l]
+
+    copy = ripple_host_snapshot.lower(
+        [state["u"]], (True,)).compile().as_text()
+    calls = [line.strip() for line in copy.splitlines()
+             if re.match(SNAPSHOT_COPY, line.strip())]
+    assert len(calls) == 1, copy
+    assert "host_snapshot" in calls[0].split("metadata=")[-1]

@@ -1,4 +1,12 @@
-"""Jitted public wrapper + graph builder for the eikonal FIM sweep."""
+"""Jitted public wrapper + graph builder for the eikonal FIM sweep.
+
+``inner == 1`` (a pure radius-1 stencil, the benchmark's sweep) streams
+the field through VMEM in full-width bands (``eikonal_fim_stream``),
+whose height the interior's shape sets (``stream_block``); an output that
+does not fill one ``(8, 128)`` float32 tile takes ``godunov_update``.
+``inner > 1`` keeps the staged kernel (``eikonal_fim_pallas``): its
+answer depends on its tile, which ``eikonal_fim_ref`` mirrors, so its
+tile is part of the algorithm and not a speed knob."""
 
 from functools import partial
 from typing import Optional
@@ -8,7 +16,9 @@ import jax
 from repro.core.graph import Graph, exclusive_padded_access
 from repro.core.tensor import DistTensor
 from repro.tuning.tiles import resolve_tile
-from .kernel import DEFAULT_BLOCK, TILE_KERNEL, eikonal_fim_pallas
+from .kernel import (DEFAULT_BLOCK, STREAM_TILE_KERNEL, TILE_KERNEL,
+                     eikonal_fim_pallas, eikonal_fim_stream, godunov_update,
+                     stream_block, stream_fits)
 from .ref import eikonal_fim_ref
 
 
@@ -16,24 +26,36 @@ from .ref import eikonal_fim_ref
          static_argnames=("h", "inner", "block", "use_pallas"))
 def _eikonal_fim_jit(phi_haloed, source_mask, h, *, inner: int, block,
                      use_pallas: bool):
-    if use_pallas:
+    if not use_pallas:
+        return eikonal_fim_ref(phi_haloed, source_mask, h, inner=inner,
+                               block=block)
+    if inner > 1:
         return eikonal_fim_pallas(phi_haloed, source_mask, h, inner=inner,
                                   block=block)
-    return eikonal_fim_ref(phi_haloed, source_mask, h, inner=inner, block=block)
+    if stream_fits(source_mask.shape, phi_haloed.dtype):
+        return eikonal_fim_stream(phi_haloed, source_mask, h, block=block)
+    return godunov_update(phi_haloed, source_mask, h)
 
 
 def eikonal_fim_sweep(phi_haloed, source_mask, h, *, inner: int = 4,
                       block=None, use_pallas: bool = True):
-    """``inner`` VMEM-staged FIM Jacobi sweeps per tile over a haloed
-    ``(nx+2, ny+2)`` level-set array (paper Table 5); returns the
-    updated ``(nx, ny)`` interior.
+    """``inner`` FIM Jacobi sweeps per tile over a haloed ``(nx+2, ny+2)``
+    level-set array (paper Table 5); returns the updated ``(nx, ny)``
+    interior.
 
     ``block=None`` resolves the ``(bx, by)`` tile through the
     autotuner's ambient tile scope (``repro.tuning.tiles``); an explicit
-    ``block`` always wins, and outside any scope the kernel default
-    applies."""
+    ``block`` always wins.  Outside any scope ``inner == 1`` on the
+    Pallas path takes the band ``stream_block`` sets from the interior's
+    shape (tuned as ``eikonal_stream``), and ``inner > 1`` the staged
+    kernel's ``DEFAULT_BLOCK`` (tuned as ``eikonal``)."""
     interior = tuple(s - 2 for s in phi_haloed.shape)
-    block = resolve_tile(TILE_KERNEL, block, DEFAULT_BLOCK, shape=interior)
+    if inner == 1 and use_pallas and stream_fits(interior, phi_haloed.dtype):
+        block = resolve_tile(STREAM_TILE_KERNEL, block,
+                             stream_block(interior), shape=interior)
+    else:
+        block = resolve_tile(TILE_KERNEL, block, DEFAULT_BLOCK,
+                             shape=interior)
     return _eikonal_fim_jit(phi_haloed, source_mask, h, inner=inner,
                             block=block, use_pallas=use_pallas)
 
@@ -60,17 +82,16 @@ def make_eikonal_graph(
     result depend on the tile decomposition (paper's FIM ghost-zone
     trade) — so only the default ``inner=1`` (a pure radius-1 stencil)
     is decomposition-invariant and value-identical between the
-    overlapped and synchronous lowerings.  The reference path
-    (``use_pallas=False``) lowers ``inner=1`` without any tile grid, so
-    boundary strips of any thickness work; the Pallas path, and
-    ``inner > 1``, need a ``block`` that tiles every strip extent.
+    overlapped and synchronous lowerings.  ``inner=1`` takes strips of
+    any thickness: the reference path (``use_pallas=False``) lowers it
+    without any tile grid, and the Pallas path streams a strip that fills
+    an ``(8, 128)`` tile and updates a thinner one with the jnp formula.
+    ``inner > 1`` needs a ``block`` that tiles every strip extent.
 
     ``graph=`` appends the sweep node to an existing builder (see
     ``make_flux_difference_graph``) so independent kernel nodes can share
     one DAG-scheduled jit segment.
     """
-    from .kernel import godunov_update
-
     def sweep(p_haloed, m):
         if inner == 1 and not use_pallas:
             return godunov_update(p_haloed, m, h)

@@ -187,6 +187,72 @@ def test_eikonal_sweep(n):
                                atol=1e-6)
 
 
+def _haloed_field(shape, source=None, seed=0):
+    """A float32 field, its source mask and the field padded
+    transmissively: random values with 5% sources and one negative cell
+    that is not a source, or, with ``source``, one source in a far
+    field."""
+    rng = np.random.default_rng(seed)
+    if source is None:
+        mask = jnp.asarray(rng.uniform(size=shape) < 0.05).at[1, 1].set(False)
+        phi = jnp.asarray(rng.uniform(size=shape), jnp.float32)
+        phi = phi.at[1, 1].set(-0.25)
+    else:
+        mask = jnp.zeros(shape, bool).at[source].set(True)
+        phi = jnp.full(shape, 4.0, jnp.float32)
+    phi = jnp.where(mask, 0.0, phi)
+    return mask, pad_boundary_only(pad_boundary_only(phi, axis=0, width=1),
+                                   axis=1, width=1)
+
+
+@pytest.mark.parametrize("shape,block,source", [
+    ((64, 256), (32, 256), None),
+    ((72, 256), (32, 256), None),
+    ((40, 256), (64, 256), None),
+    ((8, 128), None, None),
+    ((48, 384), None, None),
+    ((64, 256), None, (63, 255)),
+    ((40, 300), (16, 128), None),
+], ids=["band_multiple", "ragged_rows", "under_one_band", "one_tile",
+        "non_square", "corner_source", "column_panels"])
+def test_eikonal_stream_matches_godunov_update(shape, block, source):
+    """The streaming sweep (``inner == 1``) is the jnp Godunov sweep bit
+    for bit.  Both run jitted: XLA's CPU fusions contract multiply-adds,
+    which op-by-op dispatch does not."""
+    from repro.kernels.eikonal.kernel import (eikonal_fim_stream,
+                                              godunov_update)
+    mask, ph = _haloed_field(shape, source)
+    h = 1.0 / shape[0]
+    got = jax.jit(lambda p, m: eikonal_fim_stream(p, m, h, block=block))(
+        ph, mask)
+    want = jax.jit(lambda p, m: godunov_update(p, m, h))(ph, mask)
+    assert got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("block", [(8, 128), (16, 256)])
+def test_eikonal_staged_inner_sweeps_match_ref(block):
+    """``inner > 1`` keeps the staged kernel: frozen-halo sweeps per
+    tile, which ``eikonal_fim_ref`` mirrors for the same block."""
+    from repro.kernels.eikonal.ops import eikonal_fim_ref, eikonal_fim_sweep
+    mask, ph = _haloed_field((32, 256), (16, 100))
+    out = eikonal_fim_sweep(ph, mask, 1.0 / 32, inner=4, block=block)
+    ref = eikonal_fim_ref(ph, mask, 1.0 / 32, inner=4, block=block)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_eikonal_sweep_takes_the_jnp_formula_for_a_thin_strip():
+    """An ``inner == 1`` output thinner than one (8, 128) tile (the strips
+    of an overlapped 2-D decomposition) is updated by the jnp formula."""
+    from repro.kernels.eikonal.kernel import godunov_update
+    from repro.kernels.eikonal.ops import eikonal_fim_sweep
+    for shape in ((3, 256), (64, 3)):
+        mask, ph = _haloed_field(shape)
+        got = eikonal_fim_sweep(ph, mask, 1.0 / 64, inner=1)
+        want = jax.jit(lambda p, m: godunov_update(p, m, 1.0 / 64))(ph, mask)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_eikonal_converges_to_distance():
     """Iterated FIM sweeps approach the euclidean distance field near the
     source (the paper's reinitialization use-case)."""

@@ -108,6 +108,14 @@ def _eikonal(s, block=(8, 128)):
             _sds(s, (GRID + 2, GRID + 2)), _sds(s, (GRID, GRID), jnp.bool_))
 
 
+def _eikonal_stream(s, block=None):
+    from repro.kernels.eikonal.kernel import eikonal_fim_stream
+
+    return (lambda p, m: eikonal_fim_stream(p, m, 1.0 / GRID, block=block,
+                                            interpret=False),
+            _sds(s, (GRID + 2, GRID + 2)), _sds(s, (GRID, GRID), jnp.bool_))
+
+
 def _attention(s, blocks=(128, 128), fused=False):
     from repro.kernels.attention.kernel import flash_attention_pallas
 
@@ -150,6 +158,7 @@ KERNELS = {
     "particle_aosoa": _particle,
     "flux_soa": _flux,
     "eikonal": _eikonal,
+    "eikonal_stream": _eikonal_stream,
     "force_update_soa": _force_update,
     "attention_soa": _attention,
     "attention_fused_kv": lambda s: _attention(s, fused=True),
@@ -177,6 +186,8 @@ def _tile_cases():
     cases += [("eikonal", b) for b in eikonal.tile_candidates((GRID, GRID))]
     cases += [("attention", b)
               for b in attention.tile_candidates((1024, 1024))]
+    cases += [("eikonal_stream", b)
+              for b in eikonal.stream_candidates((GRID, GRID))]
     return cases
 
 
@@ -185,7 +196,7 @@ def _tile_cases():
                          if isinstance(v, tuple) else v)
 def test_tile_candidate_compiles_for_v5e(one_chip, kernel, tile):
     build = {"flux": _flux, "eikonal": _eikonal, "force_update": _force_update,
-             "attention": _attention}[kernel]
+             "attention": _attention, "eikonal_stream": _eikonal_stream}[kernel]
     fn, *args = build(one_chip, tile)
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
@@ -267,6 +278,55 @@ def test_eikonal_sweep_region_keeps_its_kernel_name_for_v5e(one_chip,
              if re.match(EIKONAL_KERNEL, line.strip())]
     assert calls, "no tpu_custom_call named _eikonal_fim_jit"
     assert all("/fim_sweep/sweep/" in c for c in calls), calls
+
+
+def test_eikonal_solve_streams_its_sweep_for_v5e(one_chip, monkeypatch):
+    """The benchmark's solve (``inner=1`` on the (2050, 2050) haloed field
+    in the device loop): one streaming Pallas call a sweep under the name
+    the roofline reader matches, fed by no full-field pad and no mask
+    convert inside the loop, with the VMEM limit the shape rule sets."""
+    import re
+
+    from repro.core import (DistTensor, Executor, Graph, MaxReducer,
+                            make_reduction_result)
+    from repro.kernels.eikonal import kernel
+    from repro.kernels.eikonal.ops import make_eikonal_graph
+
+    monkeypatch.setattr(kernel, "interpret_mode",
+                        lambda interpret=None: False)
+    phi = DistTensor("phi", (GRID, GRID), halo=(1, 1))
+    mask = DistTensor("mask", (GRID, GRID), dtype=jnp.bool_)
+    prev = DistTensor("phi_prev", (GRID, GRID))
+    change = DistTensor("change", (GRID, GRID))
+    res = make_reduction_result("res", init=1.0)
+    loop = make_eikonal_graph(phi, mask, 1.0 / GRID, inner=1, overlap=False,
+                              use_pallas=True, graph=Graph(name="fim_sweep"))
+    loop.then_split(lambda p, q, _d: (p, jnp.abs(p - q)), phi, prev, change,
+                    writes=(1, 2))
+    loop.then_reduce(change, res, MaxReducer())
+    loop.conditional(lambda s: s["res"] > 0)
+    solve = Graph(name="fim_solve")
+    solve.emplace(loop)
+    ex = Executor(solve)
+    state = {name: _sds(one_chip, ex._eff(t).storage_shape, t.dtype)
+             for name, t in ex.tensors.items()}
+    state["res"] = _sds(one_chip, (), res.dtype)
+    fn, _ = ex._region_executable(ex._regions[0])
+    text = fn.jit_fn.lower(
+        *ex._split_state(state, fn.donate_keys, fn.read_only)
+    ).compile().as_text()
+    lines = [line.strip() for line in text.splitlines()]
+    calls = [line for line in lines if re.match(EIKONAL_KERNEL, line)]
+    assert len(calls) == 1, [l for l in lines if "tpu_custom_call" in l]
+    operands = re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
+    defs = {line.split(" = ")[0].lstrip("%"): line for line in lines
+            if " = " in line}
+    fed_by = [defs[o.strip().lstrip("%")] for o in operands.split(",")]
+    assert not [d for d in fed_by if " pad(" in d or " convert(" in d], fed_by
+    assert not [l for l in lines if re.search(r"f32\[20\d\d,\d+\].* pad\(", l)]
+    limit = kernel.stream_vmem_limit(*kernel.stream_block((GRID, GRID)))
+    sizes = re.search(r'"scoped_memory_configs":\[([^\]]*)\]', calls[0])
+    assert f'"size":"{limit}"' in sizes.group(1), sizes.group(1)
 
 
 # -- the split FORCE update's kernel, which its roofline reads -----------------

@@ -19,9 +19,8 @@ __all__ = ["time_fn", "time_fn_split", "Csv", "gbps"]
 
 def gbps(nbytes: float, ms: float) -> float:
     """Achieved bandwidth in GB/s from known bytes-moved and measured
-    milliseconds — the roofline-comparable number every table row
-    reports next to its wall-clock (see roofline.HBM_BW for the peak
-    the fraction is taken against on the reference TPU)."""
+    milliseconds — the bandwidth every table row reports next to its
+    wall-clock."""
     return nbytes / max(ms * 1e-3, 1e-12) / 1e9
 
 

@@ -25,26 +25,15 @@ def main(argv=None) -> int:
     from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    from . import (chaos_recovery, dispatch_overhead, fig13_scaling,
-                   overlap_gain, roofline, serve_load, table2_saxpy,
-                   table3_particle, table4_flux, table5_eikonal,
-                   table_layout, table_tuned)
+    from . import (chaos_recovery, fig13_scaling, overlap_gain,
+                   table2_saxpy, table3_particle, table4_flux,
+                   table5_eikonal, table_layout, table_tuned)
     jobs = [
         ("Chaos recovery (injected faults: replay cost + latency)",
          lambda: chaos_recovery.main(
              num_steps=40 if not args.full else 200)),
-        ("Dispatch overhead (region compiler vs per-segment)",
-         lambda: dispatch_overhead.main(
-             steps=30 if not args.full else 100,
-             n=4096 if not args.full else 1 << 20)),
         ("Async overlap gain (event-driven host callbacks)",
          overlap_gain.main),
-        ("Roofline (achieved vs peak GB/s)", lambda: roofline.main(
-            n=1 << 20 if not args.full else 1 << 24)),
-        ("Serving load (continuous batching)",
-         lambda: serve_load.main(
-             slots=2, n_requests=6, prompt_len=10, gen=8,
-             tuned=args.full)),
         ("Tuned vs heuristic (measured autotuner)", table_tuned.main),
         ("Layout table (AoS/SoA/AoSoA)", lambda: table_layout.main(
             saxpy_n=1 << 18 if not args.full else 1 << 22,

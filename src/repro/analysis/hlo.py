@@ -437,7 +437,7 @@ class HloCostModel:
         metadata).  Outer scans that merely contain a matching inner scan
         are not tagged.  Used to attribute the jnp chunked-attention
         scan's HBM traffic so the Pallas-kernel projection can substitute
-        it (benchmarks/roofline --flash-credit)."""
+        it (``benchmarks/flash_projection``)."""
         total = 0.0
 
         def walk(name: str, mult: float, inside: bool) -> None:

@@ -29,8 +29,7 @@ decisions* (DESIGN.md §2/§4), which this executor makes explicitly:
   fused fori fast path takes ``steps`` as a dynamic argument (distinct
   step counts share one trace) and the non-fused path loops over cached
   region executables with no eager relayout dispatch while consecutive
-  iterations agree on layout.  ``Executor(regions=False)`` is the
-  per-segment-dispatch escape hatch.  On the chip the benchmark's
+  iterations agree on layout.  On the chip the benchmark's
   ``dispatch_ms.euler`` reads the host time of each executable call (the
   ``ripple.dispatch`` spans, see ``repro.tracing``);
 * a segment with partitioned tensors is lowered through one ``shard_map``
@@ -322,8 +321,7 @@ class _AsyncRun:
         names the node on its ``ripple.host`` span."""
         self.check()
         _fault_trip("executor.dispatch", detail=f"region{region_index}")
-        if len(self.tasks) >= self.max_inflight:
-            self._wait_oldest()
+        self.hold(self.max_inflight - 1, reason="inflight")
         if self.donate:
             vals = _snapshot_for_host(vals)
         # the task lets go of its arguments (the snapshot) when it
@@ -373,14 +371,18 @@ class _AsyncRun:
             err.site = "executor.host"
             raise err from None
 
-    def _wait_oldest(self, reason: str = "inflight") -> None:
-        region_index, fut = self.tasks[0]
-        try:
-            with tracing.span("ripple.host_wait", reason=reason):
-                self._timed_result(region_index, fut)
-        except _HostTaskCancelled:
-            pass
-        self.tasks.pop(0)
+    def hold(self, n: int, reason: str = "slot") -> None:
+        """Wait for the oldest callbacks, each under a
+        ``ripple.host_wait`` span of ``reason``, until at most ``n`` are
+        in flight."""
+        while len(self.tasks) > n:
+            region_index, fut = self.tasks[0]
+            try:
+                with tracing.span("ripple.host_wait", reason=reason):
+                    self._timed_result(region_index, fut)
+            except _HostTaskCancelled:
+                pass
+            self.tasks.pop(0)
 
     def check(self) -> None:
         """Surface an already-failed callback without waiting on the
@@ -1144,17 +1146,14 @@ class Executor:
       barrier, every host node a break) — the escape hatch and the
       reference semantics the property tests compare against.
 
-    ``regions`` (default True) enables the region compiler: maximal runs
-    of device/loop segments become one jitted executable each, with the
-    boundary relayouts traced inside, cached process-wide by plan
-    signature.  ``regions=False`` falls back to per-segment dispatch with
-    eager Python relayout glue (the pre-region behavior).  The host time
-    of each executable call is the ``ripple.dispatch`` span, which the
-    benchmark's ``dispatch_ms.euler`` reads on the chip.
+    Maximal runs of device/loop segments become one jitted executable
+    each (a *region*), with the boundary relayouts traced inside, cached
+    process-wide by plan signature.  The host time of each executable
+    call is the ``ripple.dispatch`` span, which the benchmark's
+    ``dispatch_ms.euler`` reads on the chip.
 
-    Both schedules (and both region modes) produce bitwise-identical
-    state for any valid graph; the DAG schedule just gives XLA more to
-    overlap per dispatch, and regions cut the per-step dispatch count.
+    Both schedules produce bitwise-identical state for any valid graph;
+    the DAG schedule just gives XLA more to overlap per dispatch.
 
     ``tune`` selects the measured autotuner (``repro.tuning``):
 
@@ -1199,7 +1198,7 @@ class Executor:
     def __init__(self, graph: Graph, mesh: Optional[Mesh] = None,
                  donate: bool = True,
                  layout_overrides: Optional[dict[str, Layout]] = None,
-                 schedule: str = "dag", regions: bool = True,
+                 schedule: str = "dag",
                  async_regions: bool = True,
                  tune: str = "off",
                  tune_budget: Optional[Any] = None,
@@ -1220,7 +1219,6 @@ class Executor:
         self.mesh = mesh
         self.donate = donate
         self.schedule = schedule
-        self.regions_enabled = bool(regions)
         # event-driven region dispatch (host callbacks on the pool, no
         # inter-region block_until_ready); False = synchronous escape
         # hatch with bitwise-identical results.  Not part of the plan
@@ -1470,7 +1468,6 @@ class Executor:
         self.plan.degradations = self._degradations
         self._fetched: set = set()        # executable keys this instance saw
         self._sub_execs: dict[int, "Executor"] = {}   # per loop segment
-        self._jitted: dict[int, Callable] = {}        # regions=False path
         self.eager_relayouts = 0   # conversions dispatched outside a trace
 
     def _collect_halo_schedule(self) -> None:
@@ -1966,8 +1963,7 @@ class Executor:
             sub = self._sub_execs[i] = Executor(
                 payload, self.mesh, donate=False,
                 layout_overrides=self.plan.per_segment[i],
-                schedule=self.schedule, regions=self.regions_enabled,
-                async_regions=self.async_regions,
+                schedule=self.schedule, async_regions=self.async_regions,
                 tile_overrides=self._tile_config)
         return sub
 
@@ -2150,33 +2146,6 @@ class Executor:
         with tile_scope(self._tile_config):
             return fn.jit_fn.lower(*args).compile().as_text()
 
-    # -- segment compilation (regions=False per-segment dispatch) -----------
-    def _device_fn(self, levels, seg: int) -> Callable:
-        sharded = self._sharded
-
-        def body(state):
-            return self._lower_levels(levels, state, sharded,
-                                      dict(self._state_layouts))
-
-        def call(state):
-            if not sharded:
-                return body(state)
-            # specs must cover exactly the state dict; build lazily per call
-            specs = self._state_specs(state, self._state_layouts)
-            fn = jax.shard_map(body, mesh=self.mesh, in_specs=(specs,),
-                               out_specs=specs, check_vma=False)
-            return fn(state)
-
-        call.__name__ = f"{self._exe_name}_segment{seg}"
-        return jax.jit(call, donate_argnums=0 if self.donate else ())
-
-    def _loop_fn(self, sub: Graph, seg: int) -> Callable:
-        def call(state):
-            return self._lower_loop(sub, seg, state)
-
-        call.__name__ = f"{self._exe_name}_segment{seg}"
-        return jax.jit(call, donate_argnums=0 if self.donate else ())
-
     # -- public execution -----------------------------------------------------
     @contextmanager
     def _layout_epoch(self):
@@ -2190,51 +2159,47 @@ class Executor:
         finally:
             self._state_layouts = dict(self.plan.initial)
 
-    def _async_ctx(self) -> Optional[_AsyncRun]:
-        """A fresh dispatcher context when the event-driven runtime is
-        active (async on, region path, and a host region exists to
-        overlap) — None means the pass runs exactly as before."""
-        if not (self.async_regions and self.regions_enabled):
-            return None
-        if not any(r.kind == "host" for r in self._regions):
-            return None
-        return _AsyncRun(self.donate, self.host_timeout)
-
-    def _path(self) -> str:
-        return "regions" if self.regions_enabled else "segments"
-
-    def __call__(self, state: dict) -> dict:
-        with tracing.span("ripple.run", new_run=True, graph=self.graph.name,
-                          steps=1, path=self._path()), \
-                self._layout_epoch():
-            ctx = self._async_ctx()
+    @contextmanager
+    def _epoch(self, settle: Optional[Callable[[], Any]] = None):
+        """One dispatcher epoch around a call's passes.  It yields the
+        call's ``_AsyncRun``: a fresh one when the event-driven runtime
+        is on and a host region exists to overlap, else None (the passes
+        run synchronously).  On success it drains the pooled callbacks
+        under ``ripple.host_wait`` (reason ``drain``: the first callback
+        exception re-raises there) and counts a clean pass; on any
+        exception it aborts the callbacks, records the failure for the
+        ladder and re-raises.  Where callbacks are in flight, ``settle``
+        returns the state the device must finish first, so that the
+        drain holds only host work the device's steps did not hide."""
+        with self._layout_epoch():
+            overlap = self.async_regions and any(
+                r.kind == "host" for r in self._regions)
+            ctx = _AsyncRun(self.donate, self.host_timeout) if overlap \
+                else None
             try:
-                state = self._pass_once(dict(state), ctx)
-                state = self._restore_initial_layouts(dict(state))
+                yield ctx
                 if ctx is not None:
+                    if settle is not None and ctx.tasks:
+                        jax.block_until_ready(settle())
                     with tracing.span("ripple.host_wait", reason="drain"):
                         ctx.drain()
-                self._note_clean_pass()
-                tracing.count("halo.bytes", self._halo_pass_bytes)
-                return state
             except BaseException as exc:
                 if ctx is not None:
                     ctx.abort()
                 self.record_failure(exc)
                 raise
+            self._note_clean_pass()
 
-    def _pass_once(self, state: dict, ctx: Optional[_AsyncRun] = None,
-                   index: int = 0) -> dict:
-        """Pass ``index`` of a call over the region (or segment)
-        schedule; a periodic host node runs only where it is due."""
-        if self.regions_enabled:
-            return self._run_regions_once(state, ctx, index)
-        return self._call_segments(state, index)
+    def __call__(self, state: dict) -> dict:
+        """One pass over the region schedule: pass 0 of a ``run()``, so
+        every periodic host node is due."""
+        return self._traced_run(state, 1, "regions")
 
     def _run_regions_once(self, state: dict,
                           ctx: Optional[_AsyncRun] = None,
                           index: int = 0) -> dict:
-        """One pass over the region schedule: each device region is ONE
+        """Pass ``index`` of a call over the region schedule (a periodic
+        host node runs only where it is due): each device region is ONE
         cached executable call (its relayouts and halo glue run inside
         the trace); host work runs eagerly between regions.  Layout
         bookkeeping is runtime-driven, so repeated passes re-dispatch
@@ -2307,53 +2272,6 @@ class Executor:
                               node=label):
                 node.fn(*vals)
 
-    def _call_segments(self, state: dict, index: int = 0) -> dict:
-        """Per-segment dispatch (``regions=False``): one jit call per
-        segment with eager relayout glue between them; relayouts are
-        runtime-driven from the current physical layouts, so repeated
-        passes only convert where consecutive iterations disagree."""
-        for i, (kind, payload) in enumerate(self._segments):
-            if kind == "host" and payload.every is not None \
-                    and index % payload.every:
-                continue
-            state = self._apply_segment_layouts(state, i)
-            if kind == "device":
-                _fault_trip("executor.region", detail=f"segment{i}")
-                fn = self._jitted.get(i)
-                if fn is None:
-                    fn = self._jitted[i] = self._device_fn(payload, i)
-                with tile_scope(self._tile_config), tracing.span(
-                        "ripple.dispatch", graph=self.graph.name,
-                        segment=i):
-                    state = fn(state)
-            elif kind == "loop":
-                fn = self._jitted.get(i)
-                if fn is None:
-                    fn = self._jitted[i] = self._loop_fn(payload, i)
-                with tile_scope(self._tile_config), tracing.span(
-                        "ripple.dispatch", graph=self.graph.name,
-                        segment=i):
-                    state = fn(state)
-            elif kind == "host_loop":
-                sub_exec = self._sub_executor(i)
-                # while semantics: check before the first iteration too
-                while _read_condition(payload, state):
-                    state = sub_exec(state)
-            elif kind == "host":
-                node: Node = payload
-                with tracing.span("ripple.host_wait", reason="barrier"):
-                    jax.block_until_ready(jax.tree_util.tree_leaves(state))
-                _fault_trip("executor.host", detail=f"segment{i}")
-                if node.fn is not None:
-                    vals = self._resolve_args(
-                        node, state, False, self._state_layouts) \
-                        if node.args else []
-                    with tracing.span("ripple.host", segment=i,
-                                      node=self._scopes.get(id(node),
-                                                            node.name)):
-                        node.fn(*vals)
-        return state
-
     def run(self, state: dict, steps: int) -> dict:
         """Execute the whole graph ``steps`` times (graphs are built once,
         executed many — paper §5.3).  Device-only graphs without a
@@ -2369,44 +2287,30 @@ class Executor:
         # vertices are device nodes and leading periodic host nodes
         # lowers its passes to device code, whatever the schedule mode
         # (any other host node must run between jit calls every step,
-        # so it breaks the fori fusion).  regions=False escapes the
-        # fused/cached machinery entirely — the escape hatch must not
-        # route through what it escapes.
+        # so it breaks the fori fusion)
         hosts = self._periodic_hosts
-        fused = self.regions_enabled and self.graph.condition is None \
-            and hosts is not None
-        path = ("fused+host" if hosts else "fused") if fused \
-            else self._path()
+        if self.graph.condition is not None or hosts is None:
+            return self._traced_run(state, steps, "regions")
+        return self._traced_run(state, steps,
+                                "fused+host" if hosts else "fused")
+
+    def _traced_run(self, state: dict, steps: int, path: str) -> dict:
+        """``steps`` passes along ``path`` under the ``ripple.run`` span."""
         with tracing.span("ripple.run", new_run=True, graph=self.graph.name,
                           steps=steps, path=path):
-            if fused:
-                state = self._run_fused(state, steps)
-            else:
+            if path == "regions":
                 state = self._run_passes(state, steps)
+            else:
+                state = self._run_fused(state, steps)
             tracing.count("halo.bytes", self._halo_pass_bytes * steps)
             return state
 
     def _run_passes(self, state: dict, steps: int) -> dict:
-        """``steps`` passes over the region (or segment) schedule."""
-        with self._layout_epoch():
-            ctx = self._async_ctx()
-            state = dict(state)
-            try:
-                for i in range(steps):
-                    state = self._pass_once(dict(state), ctx, i)
-                state = self._restore_initial_layouts(dict(state))
-                if ctx is not None:
-                    # completion point of the epoch: every pooled host
-                    # callback has run (or its failure re-raises here)
-                    with tracing.span("ripple.host_wait", reason="drain"):
-                        ctx.drain()
-                self._note_clean_pass()
-                return state
-            except BaseException as exc:
-                if ctx is not None:
-                    ctx.abort()
-                self.record_failure(exc)
-                raise
+        """``steps`` passes over the region schedule."""
+        with self._epoch() as ctx:
+            for i in range(steps):
+                state = self._run_regions_once(dict(state), ctx, i)
+            return self._restore_initial_layouts(dict(state))
 
     def _build_fused_fn(self, entry_layouts: dict[str, Layout]) -> Callable:
         """Fused path executable: entry relayouts traced up front, then
@@ -2497,40 +2401,28 @@ class Executor:
         due = schedule_lib.due_passes([n.every for _, n in hosts], steps)
         if not due or due[0][0]:
             due.insert(0, (0, []))
-        with self._layout_epoch():
-            ctx = self._async_ctx()
-            try:
-                _fault_trip("executor.region", detail="fused")
-                entry = dict(self._state_layouts)
-                key = ("fused", self._layout_sig(entry))
-                fn = self._fetch(key, lambda: self._build_fused_fn(entry))
-                ends = [i for i, _ in due[1:]] + [steps]
-                last = 0    # pooled tasks of the previous due pass
-                for (start, js), end in zip(due, ends):
-                    if js and ctx is not None:
-                        # two due passes' copies alive at most: the
-                        # callbacks before the previous due pass had a
-                        # chunk's time to end
-                        while len(ctx.tasks) > last:
-                            ctx._wait_oldest("slot")
-                        last = len(js)
-                    for j in js:
-                        self._host_node(*hosts[j], state, ctx)
-                    with tracing.span("ripple.dispatch",
-                                      graph=self.graph.name, region="fused"):
-                        state = fn(dict(state), end - start)
-                self._state_layouts.update(fn.exit_layouts)
-                state = self._restore_initial_layouts(dict(state))
-                if ctx is not None and ctx.tasks:
-                    jax.block_until_ready(state)
-                    with tracing.span("ripple.host_wait", reason="drain"):
-                        ctx.drain()
-            except BaseException as exc:
-                if ctx is not None:
-                    ctx.abort()
-                self.record_failure(exc)
-                raise
-            self._note_clean_pass()
+        # the epoch waits for the device to finish the state this
+        # returns (``state`` as it stands then) before the drain
+        with self._epoch(settle=lambda: state) as ctx:
+            _fault_trip("executor.region", detail="fused")
+            entry = dict(self._state_layouts)
+            key = ("fused", self._layout_sig(entry))
+            fn = self._fetch(key, lambda: self._build_fused_fn(entry))
+            ends = [i for i, _ in due[1:]] + [steps]
+            last = 0    # pooled tasks of the previous due pass
+            for (start, js), end in zip(due, ends):
+                if js and ctx is not None:
+                    # two due passes' copies alive at most: the callbacks
+                    # before the previous due pass had a chunk's time to end
+                    ctx.hold(last)
+                    last = len(js)
+                for j in js:
+                    self._host_node(*hosts[j], state, ctx)
+                with tracing.span("ripple.dispatch",
+                                  graph=self.graph.name, region="fused"):
+                    state = fn(dict(state), end - start)
+            self._state_layouts.update(fn.exit_layouts)
+            state = self._restore_initial_layouts(dict(state))
             return state
 
 

@@ -21,7 +21,7 @@ For TPU *decode reads* the AoS record keeps head_dim minor-most (exactly
 one 128-lane tile for hd=128) while SoA leaves the small Hkv axis minor —
 so the winner flips with the workload, which is precisely the paper's
 argument for making layout a one-line polymorphic knob rather than a
-rewrite.  benchmarks/roofline + EXPERIMENTS §Perf quantify both.
+rewrite.
 """
 
 from __future__ import annotations

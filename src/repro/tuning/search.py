@@ -469,7 +469,6 @@ def measure_plan(executor, key: str, budget=None) -> TuningDecision:
                       layout_overrides={**executor._layout_overrides,
                                         **layouts},
                       schedule=executor.schedule,
-                      regions=executor.regions_enabled,
                       async_regions=executor.async_regions,
                       tile_overrides=tile_cfg,
                       segment_layout_overrides=seg_over)

@@ -87,17 +87,26 @@ def test_node_runs_on_due_passes_and_sees_that_pass(every, steps,
 @pytest.mark.parametrize("async_regions", [True, False])
 @pytest.mark.parametrize("donate", [True, False])
 def test_fused_chunks_equal_pass_by_pass_bitwise(async_regions, donate):
-    """``fused+host`` against the per-segment pass-by-pass path
-    (``regions=False``) and the sequential schedule: the same final
-    state and the same snapshots, bit for bit."""
+    """``fused+host`` against one call a pass (each call one pass of the
+    region executables, the node due in each) and the sequential
+    schedule: the same final state, and the fused snapshots those the
+    calls took at the due passes, bit for bit."""
     outs = {}
-    for name, kw in {"fused": {}, "passes": {"regions": False},
+    for name, kw in {"fused": {},
                      "sequential": {"schedule": "sequential"}}.items():
         seen = []
         ex = Executor(_float_graph(seen), donate=donate,
                       async_regions=async_regions, **kw)
         st = ex.run(_start(ex, (32, 16)), 11)
         outs[name] = (st, seen)
+    seen = []
+    ex = Executor(_float_graph(seen), donate=donate,
+                  async_regions=async_regions)
+    st = _start(ex, (32, 16))
+    for _ in range(11):
+        st = ex(st)
+    assert len(seen) == 11
+    outs["passes"] = (st, seen[::4])
     assert len(outs["fused"][1]) == 3           # passes 0, 4, 8
     for name in ("passes", "sequential"):
         for k in ("u", "m"):
@@ -282,8 +291,9 @@ def _snapshot_model():
 @pytest.mark.parametrize("async_regions", [True, False])
 def test_euler_snapshots_are_their_steps(async_regions):
     """The shock-bubble at 64 x 32 with a snapshot every 3 passes: each
-    snapshot equals the pass-by-pass path's bit for bit, and the plain
-    ``jax.numpy`` reference at its step within the Euler cells' limit."""
+    snapshot equals the one a call a pass takes at its step bit for bit,
+    and the plain ``jax.numpy`` reference at its step within the Euler
+    cells' limit."""
     model = _snapshot_model()
     cfg = {
         "nx": 64, "ny": 32, "domain": [2.0, 1.0], "gamma": 1.4,
@@ -292,16 +302,24 @@ def test_euler_snapshots_are_their_steps(async_regions):
         "bubble_rho": 0.1, "mesh": None, "partition": [None, None],
         "overlap": False, "snapshot_every": 3}
     seed = 2**33 + 5
-    rings = {}
-    for name in ("fused", "passes"):
-        ring = rings[name] = model.HostRing(cfg)
+
+    def solver(ring):
         ex = Executor(model.build_solver(cfg, ring).graph,
-                      regions=name == "fused", async_regions=async_regions)
-        st = ex.init_state(u=model.euler_sb.initial_state(cfg, seed))
-        ex.run(st, 7)                         # snapshots at 0, 3, 6
-    assert rings["fused"].taken == rings["passes"].taken == 3
-    fused = [u for _, u in rings["fused"].slots]
-    passes = [u for _, u in rings["passes"].slots]
+                      async_regions=async_regions)
+        return ex, ex.init_state(u=model.euler_sb.initial_state(cfg, seed))
+
+    ring = model.HostRing(cfg)
+    ex, st = solver(ring)
+    ex.run(st, 7)                             # snapshots at 0, 3, 6
+    assert ring.taken == 3
+    fused = [u for _, u in ring.slots]        # the newest two: 3 and 6
+    ring, passes = model.HostRing(cfg), []
+    ex, st = solver(ring)
+    for i in range(7):                        # a snapshot every call
+        st = ex(st)
+        if i in (3, 6):
+            passes.append(ring.slots[-1][1])
+    assert ring.taken == 7
     for a, b in zip(fused, passes):
         np.testing.assert_array_equal(a, b)
     for u, step in zip(fused, (3, 6)):

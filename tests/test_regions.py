@@ -101,10 +101,9 @@ def test_run_fused_values_match_stepwise_calls():
     g = _chain_graph()
     ex = Executor(g, donate=False)
     st_fused = ex.run(ex.init_state(u=jnp.ones((8, 8))), steps=3)
-    ex2 = Executor(g, donate=False, regions=False)
-    st = ex2.init_state(u=jnp.ones((8, 8)))
+    st = ex.init_state(u=jnp.ones((8, 8)))
     for _ in range(3):
-        st = ex2(st)
+        st = ex(st)                      # one pass of the region executable
     for k in ("u", "ws", "smax"):
         np.testing.assert_array_equal(np.asarray(st_fused[k]),
                                       np.asarray(st[k]), err_msg=k)
@@ -128,18 +127,51 @@ def test_region_run_steady_state_is_retrace_and_dispatch_free():
     assert ex.eager_relayouts - eager0 == 1
 
 
-def test_region_equals_sequential_per_segment_dispatch():
-    """Bitwise acceptance: region-compiled DAG schedule == sequential
-    per-segment dispatch on the relayout-heavy chain."""
+def test_region_dag_schedule_equals_sequential_schedule():
+    """Bitwise acceptance: the region-compiled DAG schedule == the
+    sequential reference schedule on the relayout-heavy chain."""
     outs = {}
-    for tag, kw in (("region", dict(schedule="dag", regions=True)),
-                    ("legacy", dict(schedule="sequential", regions=False))):
-        ex = Executor(build_relayout_chain(), donate=False, **kw)
-        outs[tag] = ex.run(ex.init_state(), steps=3)
-    for k in sorted(outs["region"]):
-        np.testing.assert_array_equal(np.asarray(outs["region"][k]),
-                                      np.asarray(outs["legacy"][k]),
+    for schedule in ("dag", "sequential"):
+        ex = Executor(build_relayout_chain(), donate=False,
+                      schedule=schedule)
+        outs[schedule] = ex.run(ex.init_state(), steps=3)
+    for k in sorted(outs["dag"]):
+        np.testing.assert_array_equal(np.asarray(outs["dag"][k]),
+                                      np.asarray(outs["sequential"][k]),
                                       err_msg=k)
+
+
+@pytest.mark.parametrize("async_regions", [True, False])
+def test_run_passes_equal_stepwise_calls(async_regions):
+    """A graph that cannot fuse (a per-step host node between two device
+    regions): ``run(state, n)`` takes the region pass loop and equals
+    ``n`` calls bit for bit, in its state and in what the host node
+    saw."""
+    def build(seen):
+        u = DistTensor("u", (8, 8))
+        g = Graph(name="passes_vs_calls")
+        g.split(lambda x: x * 0.999 + jnp.sin(x) * 0.01, u, writes=(0,))
+        g.then(lambda x: seen.append(np.asarray(x).copy()),
+               exec_kind=ExecutionKind.Cpu, args=(u,))
+        g.then_split(lambda x: x + jnp.cos(x) * 0.1, u, writes=(0,))
+        return Executor(g, async_regions=async_regions)
+
+    def u0():
+        return jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 7.0
+
+    seen_run, seen_calls = [], []
+    ex = build(seen_run)
+    assert ex._periodic_hosts is None            # the pass loop, not fused
+    st_run = ex.run(ex.init_state(u=u0()), 4)
+    ex = build(seen_calls)
+    st = ex.init_state(u=u0())
+    for _ in range(4):
+        st = ex(st)
+    np.testing.assert_array_equal(np.asarray(st_run["u"]),
+                                  np.asarray(st["u"]))
+    assert len(seen_run) == len(seen_calls) == 4
+    for a, b in zip(seen_run, seen_calls):
+        np.testing.assert_array_equal(a, b)
 
 
 # -- plan signature + executable cache -----------------------------------------
@@ -217,21 +249,6 @@ def test_plan_signature_keys_kwonly_defaults_and_globals():
     finally:
         _GLOBAL_SCALE = 2.0
     assert s1 != s2
-
-
-def test_regions_false_run_escapes_the_cache_machinery():
-    """The escape hatch must not route run() through the fused/cached
-    path it exists to escape — device-only graphs dispatch per segment."""
-    g = _chain_graph()
-    ex = Executor(g, donate=False, regions=False)
-    st = ex.run(ex.init_state(u=jnp.ones((8, 8))), steps=3)
-    assert len(ex._jitted) > 0                       # per-segment jits
-    assert not any(k[0] == "fused" for k in ex._fetched)
-    ref = Executor(g, donate=False).run(
-        Executor(g, donate=False).init_state(u=jnp.ones((8, 8))), steps=3)
-    for k in ("u", "ws", "smax"):
-        np.testing.assert_array_equal(np.asarray(st[k]),
-                                      np.asarray(ref[k]), err_msg=k)
 
 
 def test_second_executor_reuses_executables_without_tracing():

@@ -212,7 +212,7 @@ def test_run_fast_path_consults_scheduler():
     ex = Executor(g)
     assert ex.dag.device_only
     st = ex.run(ex.init_state(u=jnp.ones((8, 8))), steps=3)
-    assert len(ex._jitted) == 0  # fused fori path, no per-segment jits
+    assert any(k[0] == "fused" for k in ex._fetched)  # fused fori path
 
 
 def test_run_host_node_mid_graph_breaks_fusion():

@@ -95,8 +95,8 @@ def test_recording_follows_the_profiler(tmp_path, record):
 RUNS = {
     "fused": lambda: (_stencil_graph(), {}, lambda ex, s: ex.run(s, 3)),
     "call": lambda: (_stencil_graph(), {}, lambda ex, s: ex(s)),
-    "segments": lambda: (_stencil_graph(), {"regions": False},
-                         lambda ex, s: ex.run(s, 2)),
+    "passes": lambda: (_host_graph([], barrier=True), {},
+                       lambda ex, s: ex.run(s, 2)),
     "host_pool": lambda: (_host_graph([]), {}, lambda ex, s: ex.run(s, 2)),
     "host_loop": lambda: (_loop_graph(), {}, lambda ex, s: ex(s)),
 }
@@ -218,17 +218,16 @@ def _relayout_graph(n=16):
 
 def test_eager_relayouts_are_spans_and_traced_ones_a_scope(tmp_path,
                                                           record):
-    ex = Executor(_relayout_graph(), regions=False, donate=False)
+    ex = Executor(_relayout_graph(), donate=False)
     st = ex.init_state()
     eager0 = ex.eager_relayouts
     rec = _traced(tmp_path, lambda: ex.run(st, 2))
     spans = _named(rec, "ripple.relayout")
     assert len(spans) == ex.eager_relayouts - eager0 > 0
     assert {s.attrs["bytes"] for s in spans} == {16 * 2 * 4}
-    assert {s.attrs["segment"] for s in _named(rec, "ripple.dispatch")} \
-        == {0, 1}
-    fused = Executor(_relayout_graph(), donate=False)
-    assert "/relayout/" in fused.region_hlo(fused.init_state())
+    dispatch = _named(rec, "ripple.dispatch")
+    assert [s.attrs["region"] for s in dispatch] == [0, 0]
+    assert "/relayout/" in ex.region_hlo(ex.init_state())
 
 
 def test_xplane_host_plane_holds_the_spans(tmp_path, record):
